@@ -16,6 +16,9 @@ def main() -> None:
     args = ap.parse_args()
     which = args.only.split(",") if args.only else MODULES
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name in which:

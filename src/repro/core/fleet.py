@@ -174,9 +174,10 @@ def _kmeans_labels(feats: jnp.ndarray, key, k: int, iters: int) -> jnp.ndarray:
         lab = jnp.argmin(d2, axis=1)
         one = jax.nn.one_hot(lab, k, dtype=feats.dtype)  # (n, k)
         cnt = one.sum(axis=0)
-        new = jnp.where(
-            cnt[:, None] > 0, (one.T @ feats) / jnp.maximum(cnt[:, None], 1.0), c
-        )
+        # HIGHEST: at default precision a TPU runs this f32 matmul as bf16
+        # passes, and the chip and the CPU could cluster one fleet differently
+        sums = jnp.matmul(one.T, feats, precision=jax.lax.Precision.HIGHEST)
+        new = jnp.where(cnt[:, None] > 0, sums / jnp.maximum(cnt[:, None], 1.0), c)
         return new, None
 
     centers, _ = jax.lax.scan(step, centers, None, length=iters)

@@ -249,8 +249,6 @@ def solve_fused_batch_ring(costs, t_star, T: int, backend: str, mesh, axis: str)
     contract, bit-identical results — with ``n`` divisible by the ring size
     (the engine pads its n-bucket up to a multiple). Call under ``jax.jit``
     (the sweep engine's bucket executables do)."""
-    from jax.experimental.shard_map import shard_map
-
     ndev = int(mesh.shape[axis])
     B = costs.shape[0]
     k0 = jnp.full((B, T + 1), BIG, jnp.float32).at[:, 0].set(0.0)
@@ -258,12 +256,12 @@ def solve_fused_batch_ring(costs, t_star, T: int, backend: str, mesh, axis: str)
     body = functools.partial(
         _ring_dp_body, T=T, backend=backend, axis=axis, ndev=ndev
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, axis, None), P(None, None), P(None)),
         out_specs=(P(None, axis), P(None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(costs, k0, t_star.astype(jnp.int32))
 

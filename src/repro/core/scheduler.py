@@ -56,6 +56,7 @@ _DP_ALGORITHMS = {"dp", "dp_jax", "dp_batch", "dp_jax_pallas"}
 ALGORITHMS: Dict[str, Callable] = {
     "dp": solve_schedule_dp,
     "dp_jax": solve_schedule_dp_jax,
+    # "pallas": the TPU kernel, interpreted on the CPU and compiled on a TPU
     "dp_jax_pallas": lambda p: solve_schedule_dp_jax(p, backend="pallas"),
     "marin": marin,
     "marco": marco,

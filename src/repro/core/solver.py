@@ -231,7 +231,8 @@ class Solver:
                     validate_schedule(p, x)
             algorithms = ["dp_batch"] * len(plist)
         elif plist and algorithm in _DP_ALGORITHMS:
-            # direct dispatch (not .solve()) to keep the free k_last rows
+            # direct dispatch (not .solve()) to keep the free k_last rows;
+            # "pallas" resolves to the compiled kernel on a TPU (kernels/ops.py)
             backend = "pallas" if algorithm == "dp_jax_pallas" else None
             engine = _resolve_engine(backend, None if backend else self.engine)
 

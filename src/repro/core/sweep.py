@@ -18,9 +18,9 @@ engine:
      :func:`~repro.core.jax_dp.solve_schedule_dp_batch`.
   2. **shards** the batch axis: with a ``mesh``, inputs are placed with
      ``jax.sharding.NamedSharding`` over ``B`` (rounded up to a multiple of
-     the axis size) and GSPMD partitions the scan batch-parallel — the DP
-     has no cross-instance dependence, so sharded schedules are also
-     bit-identical. Testable on CPU via
+     the axis size) and a ``shard_map`` runs the fused solve on each
+     device's rows — the DP has no cross-instance dependence, so sharded
+     schedules are also bit-identical. Testable on CPU via
      ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 ``cache_stats()`` exposes hits/misses/compiles/evictions; ``compiles`` is
@@ -45,6 +45,7 @@ in-flight solve across other work (e.g. deeper pipeline lookahead).
 
 from __future__ import annotations
 
+import functools
 import threading
 from collections import OrderedDict
 from typing import Optional
@@ -52,7 +53,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
 
 from ..kernels.ops import resolve_backend
 from ._deprecation import warn_deprecated
@@ -126,8 +127,9 @@ def make_sweep_mesh(axis: str = "sweep"):
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (same pattern as
     tests/test_distribution.py — the flag binds at first jax init).
     """
-    devices = jax.devices()
-    return jax.make_mesh((len(devices),), (axis,))
+    # Auto, not make_mesh's default Explicit: the engine's programs place
+    # inputs by NamedSharding and split work by shard_map, not by typed shardings
+    return jax.make_mesh((len(jax.devices()),), (axis,), axis_types=(AxisType.Auto,))
 
 
 class _DeviceSchedulePart:
@@ -149,13 +151,8 @@ class _DeviceSchedulePart:
         self._mat_lock = threading.Lock()  # guards every host-side cache
 
     def done(self) -> bool:
-        """True once the device computation has finished (best-effort: jax
-        versions without ``Array.is_ready`` report False until
-        materialized)."""
-        if self._out is not None:
-            return True
-        is_ready = getattr(self._raw, "is_ready", None)
-        return bool(is_ready()) if callable(is_ready) else False
+        """True once the device computation has finished."""
+        return self._out is not None or self._raw.is_ready()
 
     def result(self) -> np.ndarray:
         """The ``(B, n)`` int64 schedules — blocks until the solve lands.
@@ -430,6 +427,18 @@ class SweepEngine:
 
         _, _, _, Tb, _ = key
         ring_mesh, ring_axis = self.ring_mesh, self.ring_axis
+        solve = functools.partial(_solve_fused_batch, T=Tb, backend=backend)
+        if self.mesh is not None:
+            # each device solves its own rows (instances are independent);
+            # shard_map, because GSPMD cannot partition a Mosaic kernel
+            rows = PartitionSpec(self.mesh_axis)
+            solve = jax.shard_map(
+                solve,
+                mesh=self.mesh,
+                in_specs=(rows, rows),
+                out_specs=(rows, rows),
+                check_vma=False,  # pallas_call outputs carry no vma types
+            )
 
         def run(costs, t_star):
             # Trace-time side effect: executes once per XLA compilation of
@@ -445,7 +454,7 @@ class SweepEngine:
                 )
             # fused DP + backtrack (DESIGN.md §12): one dispatch, and only
             # (X, K_last) leave the program — never the (n, B, T+1) argmins
-            return _solve_fused_batch(costs, t_star, Tb, backend=backend)
+            return solve(costs, t_star)
 
         return jax.jit(run)
 

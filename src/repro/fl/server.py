@@ -378,9 +378,10 @@ class FederatedServer:
         survivor ``i``'s marginal table is ``C_i(c_i + j) - C_i(c_i)`` — so
         the recovered assignment is bit-identical to a fault-free re-plan of
         the surviving cohort (asserted in tests/test_faults.py). Transient
-        solver failures retry up to ``max_attempts``; if the solver itself is
-        the failing component, the guaranteed-feasible
-        :func:`~repro.fl.faults.proportional_greedy` fallback engages. The
+        solver failures retry up to ``max_attempts``; once they are spent, the
+        guaranteed-feasible :func:`~repro.fl.faults.proportional_greedy`
+        fallback engages. Non-transient solver errors propagate, as they do
+        from the planning service. The
         returned plan carries full :class:`RecoveryInfo` provenance; its
         ``est_cost`` is re-stated for the recovered assignment on the same
         planning-time tables, so the recovery overhead is directly readable
@@ -407,7 +408,7 @@ class FederatedServer:
                 break
             except Exception as e:
                 if not is_transient(e):
-                    break  # solver is the failing component: fall back now
+                    raise  # a real solver error is not hidden behind a greedy plan
         if y is None:
             y = proportional_greedy(res_problem)
             fallback = True

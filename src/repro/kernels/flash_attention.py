@@ -257,7 +257,7 @@ def _bwd(q, k, v, o, lse, do, kind, window, softcap, scale, Bq, Bk, interpret):
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
 )
 def flash_attention(q, k, v, kind="causal", window=0, softcap=0.0,
-                    scale=None, Bq=512, Bk=512, interpret=True):
+                    scale=None, Bq=512, Bk=512, interpret=False):
     """q (B,H,Sq,D); k,v (B,Hkv,Sk,D). Returns (B,H,Sq,D)."""
     o, _ = _fwd(q, k, v, kind, window, softcap,
                 scale if scale is not None else q.shape[-1] ** -0.5, Bq, Bk, interpret)

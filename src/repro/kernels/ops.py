@@ -9,15 +9,17 @@ resolves through :data:`DISPATCH_TABLE` keyed on ``jax.default_backend()``:
   ---------|----------------|------------------------------------------------
   cpu      | ``blocked``    | tiled jnp (`kernels/blocked.py`) — cache-blocked
            |                | BT x BW walk, ~4-8x over the dense oracle
-  tpu      | ``pallas_tpu`` | Pallas TPU kernel (`kernels/minplus.py`) with
-           |                | ``BT`` tuned from the real VMEM budget
+  tpu      | ``pallas_tpu`` | Pallas TPU kernel (`kernels/minplus.py`),
+           |                | compiled by Mosaic, ``BT`` checked against VMEM
   gpu      | ``pallas_gpu`` | Pallas-GPU blocked kernel (`kernels/gpu.py`)
 
-Unknown platforms fall back to ``blocked`` (pure jnp, runs anywhere). The
-dense reference (``backend="ref"``) is retained as the small-shape oracle
-every backend is validated against; ``backend="pallas"`` keeps the
-interpret-mode TPU kernel for CPU-side kernel debugging. Resolution happens
-at Python/trace time (``jax.default_backend()`` is not a traced value), so
+Any other platform raises: no backend is chosen for a device nobody has
+compiled for. The dense reference (``backend="ref"``) is retained as the
+small-shape oracle every backend is validated against. ``backend="pallas"``
+names the TPU kernel without naming the mode: on the CPU it runs in
+interpret mode (kernel debugging in tests), on a TPU it resolves to the
+compiled ``pallas_tpu``, and elsewhere it raises. Resolution happens at
+Python/trace time (``jax.default_backend()`` is not a traced value), so
 "auto" and its resolved backend share jit caches when callers resolve
 before specializing — see :func:`resolve_backend`.
 """
@@ -49,13 +51,25 @@ BACKENDS = ("ref", "blocked", "pallas", "pallas_tpu", "pallas_gpu")
 
 def resolve_backend(backend: str | None = "auto") -> str:
     """Concrete backend name for ``backend`` (``None``/"auto" dispatch per
-    hardware). Callers that use the backend as a jit static argument or a
+    hardware; "pallas" is the interpreted kernel on the CPU and the compiled
+    one on a TPU). Callers that use the backend as a jit static argument or a
     cache key should resolve first so "auto" shares compilations with its
     resolved name."""
+    platform = jax.default_backend()
     if backend is None or backend == "auto":
-        return DISPATCH_TABLE.get(jax.default_backend(), "blocked")
+        if platform not in DISPATCH_TABLE:
+            raise ValueError(
+                f"no min-plus backend for platform {platform!r}; "
+                f"known platforms: {sorted(DISPATCH_TABLE)}"
+            )
+        return DISPATCH_TABLE[platform]
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; options: auto, {BACKENDS}")
+    if backend == "pallas" and platform != "cpu":
+        # interpret mode is for CPU tests only; on a TPU the kernel compiles
+        if platform == "tpu":
+            return "pallas_tpu"
+        raise ValueError(f'backend "pallas" runs on cpu or tpu, not {platform!r}')
     return backend
 
 
@@ -66,12 +80,9 @@ def minplus_step(kprev: jnp.ndarray, cost: jnp.ndarray, backend: str = "auto"):
         return minplus_step_ref(kprev, cost)
     if backend == "blocked":
         return minplus_blocked(kprev, cost)
-    if backend == "pallas":
-        return minplus_pallas(kprev, cost, interpret=True)
-    if backend == "pallas_tpu":
-        return minplus_pallas(
-            kprev, cost, BT=tpu_tuned_bt(kprev.shape[0], cost.shape[0]), interpret=False
-        )
+    if backend in ("pallas", "pallas_tpu"):
+        bt = tpu_tuned_bt(kprev.shape[0], cost.shape[0])
+        return minplus_pallas(kprev, cost, BT=bt, interpret=backend == "pallas")
     if backend == "pallas_gpu":
         return minplus_pallas_gpu(kprev, cost, interpret=False)
     raise ValueError(f"unknown backend {backend!r}")
@@ -84,12 +95,9 @@ def minplus_step_batch(kprev: jnp.ndarray, cost: jnp.ndarray, backend: str = "au
         return minplus_step_ref_batch(kprev, cost)
     if backend == "blocked":
         return minplus_blocked_batch(kprev, cost)
-    if backend == "pallas":
-        return minplus_pallas_batch(kprev, cost, interpret=True)
-    if backend == "pallas_tpu":
-        return minplus_pallas_batch(
-            kprev, cost, BT=tpu_tuned_bt(kprev.shape[1], cost.shape[1]), interpret=False
-        )
+    if backend in ("pallas", "pallas_tpu"):
+        bt = tpu_tuned_bt(kprev.shape[1], cost.shape[1])
+        return minplus_pallas_batch(kprev, cost, BT=bt, interpret=backend == "pallas")
     if backend == "pallas_gpu":
         return minplus_pallas_gpu_batch(kprev, cost, interpret=False)
     raise ValueError(f"unknown backend {backend!r}")

@@ -23,6 +23,7 @@ from ..fl import EnergyEstimator, FederatedServer, make_fleet, run_campaign
 from ..models import init_params, loss_fn, param_count
 from ..optim import sgd
 from ..checkpoint import save_checkpoint
+from .compile_cache import use_compile_cache
 
 
 def main():
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--checkpoint-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family not in ("dense", "moe", "ssm", "hybrid"):

@@ -140,6 +140,13 @@ def attention(
     ):
         from ..kernels.flash_attention import flash_attention
 
+        if jax.default_backend() != "cpu":
+            # its blocks break the TPU's (8, 128) tiling rule; only the
+            # interpreter runs it
+            raise NotImplementedError(
+                "attn_impl='pallas' runs the flash-attention kernel in interpret "
+                "mode on the CPU only; it does not compile for a TPU"
+            )
         bq = min(block_q or 512, Sq)
         o = flash_attention(
             jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2),

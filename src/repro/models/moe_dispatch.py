@@ -28,20 +28,6 @@ from ..launch.sharding import current_mesh, logical_to_mesh, rules, shard
 __all__ = ["route", "moe_ffn"]
 
 
-def _shard_map(body, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map where available; falls back to the pre-0.5 experimental
-    API (whose replication-check kwarg is ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
 def _act(cfg):
     return jax.nn.silu
 
@@ -207,7 +193,7 @@ def _moe_a2a(cfg, x2d, experts, gate_w, gate_idx):
         cap_send=cap_send, cap_expert=cap_expert,
     )
     expert_specs = jax.tree.map(lambda _: P(ep_axes if len(ep_axes) > 1 else ep_axes[0]), experts)
-    y = _shard_map(
+    y = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(token_axes), P(token_axes), P(token_axes), expert_specs),

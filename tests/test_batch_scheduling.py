@@ -172,9 +172,9 @@ def _random_rows(rng, B, Tp, W):
 
 
 @pytest.mark.parametrize("B,Tp,W,BT", [
-    (1, 64, 16, 32),
-    (4, 70, 33, 32),
-    pytest.param(8, 255, 64, 64, marks=pytest.mark.slow),  # larger interpret-mode sweep
+    (1, 64, 16, 128),
+    (4, 300, 33, 128),
+    pytest.param(8, 255, 64, 128, marks=pytest.mark.slow),  # larger interpret-mode sweep
 ])
 def test_batched_pallas_matches_batched_ref(B, Tp, W, BT):
     from repro.kernels import minplus_pallas_batch, minplus_step_ref_batch

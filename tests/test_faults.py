@@ -252,6 +252,30 @@ def test_recover_round_persistent_solver_failure_falls_back():
     assert flaky.fault_stats()["injected_failures"] == 3
 
 
+def test_recover_round_reraises_non_transient_solver_error():
+    """A solver error that is not transient is a real fault: recovery
+    re-raises it on the first attempt instead of planning by greedy."""
+
+    class _BrokenEngine(FlakyEngine):
+        def solve(self, problems, split_regimes=False):
+            self._tick()
+            raise RuntimeError("device program failed")
+
+        dispatch = solve
+
+    broken = _BrokenEngine(SweepEngine())
+    server, examples, rng, T = _build(seed=2, engine=broken)
+    est_problem = server.build_problem(T)
+    rp = server.plan_round(0, T, est_problem)  # plain plan: host path, no engine
+    victim = int(np.argmax(rp.assignments))
+    rf = FaultInjector(
+        FaultPlan(seed=0, client_faults=(ClientFault(0, victim, "crash", 0.2),))
+    ).round_faults(0, rp.assignments)
+    with pytest.raises(RuntimeError, match="device program failed"):
+        server.recover_round(rp, rf)
+    assert broken.fault_stats()["dispatches"] == 1
+
+
 # ---------------------------------------------------------------------------
 # campaign-level chaos
 # ---------------------------------------------------------------------------

@@ -177,19 +177,36 @@ def test_dispatch_table_resolves_auto_per_hardware():
     )
 
 
+def test_resolve_backend_interprets_only_on_cpu(monkeypatch):
+    # steer the platform the resolver sees: "pallas" is the compiled kernel
+    # on a TPU, and a platform with no backend raises instead of guessing
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_backend("auto") == "pallas_tpu"
+    assert resolve_backend("pallas") == "pallas_tpu"
+    monkeypatch.setattr(jax, "default_backend", lambda: "metal")
+    with pytest.raises(ValueError, match="no min-plus backend"):
+        resolve_backend("auto")
+    with pytest.raises(ValueError, match="runs on cpu or tpu"):
+        resolve_backend("pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert resolve_backend("pallas") == "pallas"
+
+
 def test_tpu_tuned_bt_respects_vmem_budget():
-    # the tile never overshoots the (tile-rounded) row; long-but-affordable
-    # rows get the largest tile; rows too long for VMEM residency fall
-    # back to 1024
-    assert tpu_tuned_bt(4096, 512) == 4096
-    assert tpu_tuned_bt(100, 512) == 1024
-    assert tpu_tuned_bt(60_000, 512) == 8192
-    assert tpu_tuned_bt(4_000_000, 1024) == 1024
-    for Tp, W in [(1000, 100), (100_000, 2048), (1_000_000, 512)]:
+    # short rows get a tile of whole lane tiles that does not overshoot the
+    # row; longer rows get the default tile; rows whose double-buffered
+    # padded row does not fit VMEM raise instead of reaching the compiler
+    assert tpu_tuned_bt(100, 512) == 128
+    assert tpu_tuned_bt(4096, 512) == 1024
+    assert tpu_tuned_bt(60_000, 512) == 1024
+    with pytest.raises(ValueError, match="VMEM"):
+        tpu_tuned_bt(4_000_000, 1024)
+    for Tp, W in [(1, 1), (1000, 100), (100_000, 2048), (1_000_000, 512)]:
         bt = tpu_tuned_bt(Tp, W)
-        assert bt % 1024 == 0  # (8, 128) f32 tile granularity
+        assert bt % 128 == 0  # f32 lane granularity of the output tiles
         tpad = -(-Tp // bt) * bt
-        assert 4 * (W + tpad) + 4 * W + 16 * bt <= 0.75 * 16 * 2**20 or bt == 1024
+        wp = -(-W // 128) * 128
+        assert 2 * 4 * (wp + tpad) + 2 * 2 * 4 * bt <= 16 * 2**20
 
 
 # ---------------------------------------------------------------------------

@@ -27,6 +27,13 @@ engine:
 counted by a trace-time side effect, so it reflects actual XLA tracings
 (one per bucket entry), not just cache misses.
 
+Every :meth:`SweepEngine.dispatch` is traced as named spans on the
+profiler's clock (``repro.engine.dispatch`` with its children
+``classify`` / ``pack`` / ``launch`` or ``compile`` / ``host_solve``;
+recorded only while a profile runs), and its handle carries the same
+phases' host seconds and the DP cells it launched in ``handle.phases``
+(DESIGN.md §14, "Observability").
+
 Regime-split solves (``split_regimes=True``, DESIGN.md §13) add a second
 executable kind to the same LRU: ``("marginal", B, n, W)`` buckets hold the
 jitted MarIn/MarCo selection kernel (no ``T`` in the key — workloads are
@@ -47,12 +54,14 @@ from __future__ import annotations
 
 import functools
 import threading
+import time
 from collections import OrderedDict
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import AxisType, NamedSharding, PartitionSpec
 
 from ..kernels.ops import resolve_backend
@@ -71,6 +80,7 @@ from .problem import (
 )
 
 __all__ = [
+    "DISPATCH_PHASES",
     "RegimeSplitHandle",
     "SweepEngine",
     "SweepHandle",
@@ -94,6 +104,47 @@ def bucket_shape(B: int, n: int, T: int, W: int):
     FLOPs in the T*W-dominated DP), bought once per bucket; in exchange all
     nearby shapes share one compiled executable."""
     return (_next_pow2(B), _next_pow2(n), _next_pow2(T), _next_pow2(W))
+
+
+# the keys of ``handle.phases``: host seconds of one dispatch and of its
+# phases, and the DP cells it launched (useful band cells, computed cells)
+DISPATCH_PHASES = (
+    "dispatch_s",
+    "classify_s",
+    "pack_s",
+    "launch_s",
+    "compile_s",
+    "dp_band_cells",
+    "dp_computed_cells",
+)
+
+
+class _Phase:
+    """A named span (``TraceAnnotation``) whose host seconds are also added
+    to ``phases[key]``."""
+
+    __slots__ = ("_phases", "_key", "_span", "_t0")
+
+    def __init__(self, phases: dict, key: str, name: str):
+        self._phases, self._key = phases, key
+        self._span = TraceAnnotation(name)
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._span.__enter__()
+
+    def __exit__(self, *exc_info):
+        self._span.__exit__(*exc_info)
+        self._phases[self._key] += time.perf_counter() - self._t0
+
+
+def _band_cells(b0: ProblemBatch) -> int:
+    """Cells of the banded min-plus work a 0-lower-limit batch needs:
+    ``(T' + 1) * (U'_i + 1)`` summed over every client with work to place
+    (``U'_i > 0``; a client pinned at its lower limit, the padding phantoms
+    among them, needs no min-plus). Padding to the bucket adds none."""
+    widths = np.where(b0.upper > 0, b0.upper + 1, 0).sum(axis=1)
+    return int(((b0.T + 1) * widths).sum())
 
 
 def _bucket_axes(b0: ProblemBatch):
@@ -354,6 +405,7 @@ class SweepEngine:
         )
         self._cache: OrderedDict = OrderedDict()
         self._hits = self._misses = self._compiles = self._evictions = 0
+        self._compile_s = 0.0  # host seconds in calls that traced + compiled
         self._bucket_hits: dict = {}  # bucket key -> warm-hit count
         # Guards cache + counters: solves may come from a background planner
         # thread (fl/pipeline.py) or the serve-layer coalescer concurrently
@@ -375,12 +427,15 @@ class SweepEngine:
         flat no matter how many solves run. ``per_bucket_hits`` breaks the
         warm hits down by bucket (keyed by :meth:`_bucket_label`; counts
         survive eviction — they describe traffic, not cache residency), the
-        serve layer's per-shape traffic telemetry."""
+        serve layer's per-shape traffic telemetry. ``compile_s`` is the host
+        time of the calls that built an executable (a cache miss traces and
+        compiles, or loads from the persistent compilation cache)."""
         with self._lock:
             return {
                 "hits": self._hits,
                 "misses": self._misses,
                 "compiles": self._compiles,
+                "compile_s": self._compile_s,
                 "evictions": self._evictions,
                 "entries": len(self._cache),
                 "max_entries": self.max_entries,
@@ -394,23 +449,43 @@ class SweepEngine:
         with self._lock:
             self._cache.clear()
             self._hits = self._misses = self._compiles = self._evictions = 0
+            self._compile_s = 0.0
             self._bucket_hits = {}
 
     def _entry(self, key):
+        """``(fn, built)``: the bucket's jitted executable, and whether this
+        call built it (a miss: its first call traces and compiles)."""
         with self._lock:
             fn = self._cache.get(key)
             if fn is not None:
                 self._hits += 1
                 self._bucket_hits[key] = self._bucket_hits.get(key, 0) + 1
                 self._cache.move_to_end(key)
-                return fn
+                return fn, False
             self._misses += 1
             fn = self._build(key)
             self._cache[key] = fn
             while len(self._cache) > self.max_entries:
                 self._cache.popitem(last=False)
                 self._evictions += 1
-            return fn
+            return fn, True
+
+    def _launch(self, key, phases: dict, *args):
+        """Calls the bucket's executable (JAX async dispatch: the enqueue, not
+        the device time) as span ``repro.engine.launch``, or, where the call
+        traces and compiles, as ``repro.engine.compile``; its host seconds,
+        the entry lookup included, go to ``launch_s`` or ``compile_s``."""
+        t0 = time.perf_counter()
+        fn, built = self._entry(key)
+        name = "repro.engine.compile" if built else "repro.engine.launch"
+        with TraceAnnotation(name, bucket=self._bucket_label(key)):
+            out = fn(*args)
+        dt = time.perf_counter() - t0
+        phases["compile_s" if built else "launch_s"] += dt
+        if built:
+            with self._lock:
+                self._compile_s += dt
+        return out
 
     def _build(self, key):
         backend = self.backend
@@ -460,61 +535,65 @@ class SweepEngine:
 
     # ---- solving -------------------------------------------------------
 
-    def _dispatch_dp(self, batch: ProblemBatch) -> SweepHandle:
-        b0 = remove_lower_limits(batch)
-        nb, Tb, Wb = _bucket_axes(b0)  # same math the coalescer keys on
-        if nb % self._ring_ndev:
-            # the ring splits the class axis evenly; pad the n-bucket up to a
-            # multiple of the ring size (phantom classes are inert)
-            nb = ((nb + self._ring_ndev - 1) // self._ring_ndev) * self._ring_ndev
-        Bb = _next_pow2(b0.B)
-        if Bb % self._ndev:
-            Bb = ((Bb + self._ndev - 1) // self._ndev) * self._ndev
-        padded = b0.pad_to(B=Bb, n=nb, W=Wb)
-        costs = pack_problem(padded)  # (Bb, nb, Wb) float32, BIG-saturated
-        t_star = jnp.asarray(padded.T, dtype=jnp.int32)
-        if self.mesh is not None:
-            P = PartitionSpec
-            costs = jax.device_put(
-                costs, NamedSharding(self.mesh, P(self.mesh_axis, None, None))
-            )
-            t_star = jax.device_put(
-                t_star, NamedSharding(self.mesh, P(self.mesh_axis))
-            )
-        fn = self._entry(("dp", Bb, nb, Tb, Wb))
-        X_raw, k_last = fn(costs, t_star)
+    def _dispatch_dp(self, batch: ProblemBatch, phases: dict) -> SweepHandle:
+        with _Phase(phases, "pack_s", "repro.engine.pack"):
+            b0 = remove_lower_limits(batch)
+            nb, Tb, Wb = _bucket_axes(b0)  # same math the coalescer keys on
+            if nb % self._ring_ndev:
+                # the ring splits the class axis evenly; pad the n-bucket up
+                # to a multiple of the ring size (phantom classes are inert)
+                nb = ((nb + self._ring_ndev - 1) // self._ring_ndev) * self._ring_ndev
+            Bb = _next_pow2(b0.B)
+            if Bb % self._ndev:
+                Bb = ((Bb + self._ndev - 1) // self._ndev) * self._ndev
+            phases["dp_band_cells"] += _band_cells(b0)
+            phases["dp_computed_cells"] += Bb * nb * (Tb + 1) * Wb
+            padded = b0.pad_to(B=Bb, n=nb, W=Wb)
+            costs = pack_problem(padded)  # (Bb, nb, Wb) float32, BIG-saturated
+            t_star = jnp.asarray(padded.T, dtype=jnp.int32)
+            if self.mesh is not None:
+                P = PartitionSpec
+                costs = jax.device_put(
+                    costs, NamedSharding(self.mesh, P(self.mesh_axis, None, None))
+                )
+                t_star = jax.device_put(
+                    t_star, NamedSharding(self.mesh, P(self.mesh_axis))
+                )
+        X_raw, k_last = self._launch(("dp", Bb, nb, Tb, Wb), phases, costs, t_star)
         return SweepHandle(X_raw, k_last, batch, np.asarray(padded.T, dtype=np.int32))
 
-    def _dispatch_selection(self, batch: ProblemBatch) -> _SelectionPart:
+    def _dispatch_selection(self, batch: ProblemBatch, phases: dict) -> _SelectionPart:
         """Launches the MarIn/MarCo slice on the jitted selection kernel
         from its own shape bucket (``("marginal", B, n, W)`` — no ``T`` in
         the key: the workload is a traced input, not a shape). Marginal
         buckets share the engine's LRU and counters with the DP buckets.
         Inputs are not mesh-sharded: selection solves are orders of
         magnitude smaller than the DPs they replace."""
-        b0 = remove_lower_limits(batch)
-        if b0.W < 2:  # every resource pinned at its lower limit: T' == 0
-            zeros = np.zeros((batch.B, batch.n), dtype=np.int64)
-            return _HostPart(
-                restore_lower_limits(batch, zeros), np.zeros(batch.B)
+        with _Phase(phases, "pack_s", "repro.engine.pack"):
+            b0 = remove_lower_limits(batch)
+            if b0.W < 2:  # every resource pinned at its lower limit: T' == 0
+                zeros = np.zeros((batch.B, batch.n), dtype=np.int64)
+                return _HostPart(
+                    restore_lower_limits(batch, zeros), np.zeros(batch.B)
+                )
+            Bb, nb, _, Wb = bucket_shape(b0.B, b0.n, 1, b0.W)
+            padded = b0.pad_to(B=Bb, n=nb, W=Wb)
+            args = (
+                pack_problem(padded),
+                jnp.asarray(padded.upper, jnp.int32),
+                jnp.asarray(padded.T, jnp.int32),
             )
-        Bb, nb, _, Wb = bucket_shape(b0.B, b0.n, 1, b0.W)
-        padded = b0.pad_to(B=Bb, n=nb, W=Wb)
-        fn = self._entry(("marginal", Bb, nb, Wb))
-        x_raw, obj_raw = fn(
-            pack_problem(padded),
-            jnp.asarray(padded.upper, jnp.int32),
-            jnp.asarray(padded.T, jnp.int32),
-        )
+        x_raw, obj_raw = self._launch(("marginal", Bb, nb, Wb), phases, *args)
         return _SelectionPart(x_raw, obj_raw, batch)
 
     @staticmethod
     def _host_part(batch: ProblemBatch, algorithm: str) -> _HostPart:
         """MarDecUn / MarDec slice: solved eagerly on the host (numpy) at
-        dispatch time."""
-        X = MARGINAL_BATCH_ALGORITHMS[algorithm](batch)
-        b0 = remove_lower_limits(batch)
-        obj = total_cost_batch(b0, X - batch.lower)
+        dispatch time, as span ``repro.engine.host_solve``."""
+        with TraceAnnotation("repro.engine.host_solve"):
+            X = MARGINAL_BATCH_ALGORITHMS[algorithm](batch)
+            b0 = remove_lower_limits(batch)
+            obj = total_cost_batch(b0, X - batch.lower)
         return _HostPart(X, obj)
 
     @staticmethod
@@ -552,34 +631,59 @@ class SweepEngine:
         ``False`` keeps the documented contract of bit-identity with
         :func:`~repro.core.jax_dp.solve_schedule_dp_batch` for every
         instance. MarDec sub-batches compute at dispatch time (host code
-        has no async seam)."""
-        batch = (
-            problems
-            if isinstance(problems, ProblemBatch)
-            else ProblemBatch.from_problems(problems)
-        )
-        batch.validate()
+        has no async seam).
+
+        The returned handle's ``phases`` dict (keys
+        :data:`DISPATCH_PHASES`) holds this call's host seconds in all
+        (``dispatch_s``) and by phase: regime classification
+        (``classify_s``), validation, slicing, padding and packing
+        (``pack_s``), the executable calls (``launch_s``; a call that
+        compiled counts under ``compile_s`` instead), and the DP's useful
+        band cells (``dp_band_cells``) against the cells its executables
+        compute over their buckets (``dp_computed_cells``)."""
+        t0 = time.perf_counter()
+        phases = dict.fromkeys(DISPATCH_PHASES, 0)
+        with TraceAnnotation("repro.engine.dispatch"):
+            handle = self._dispatch(problems, split_regimes, phases)
+        phases["dispatch_s"] = time.perf_counter() - t0
+        handle.phases = phases
+        return handle
+
+    def _dispatch(self, problems, split_regimes: bool, phases: dict):
+        with _Phase(phases, "pack_s", "repro.engine.pack"):
+            batch = (
+                problems
+                if isinstance(problems, ProblemBatch)
+                else ProblemBatch.from_problems(problems)
+            )
+            batch.validate()
         if not split_regimes:
-            return self._dispatch_dp(batch)
-        algs = select_algorithm_batch(batch)
-        groups: dict = {}
-        for b, alg in enumerate(algs):
-            key = "selection" if alg in ("marin", "marco") else alg
-            groups.setdefault(key, []).append(b)
+            return self._dispatch_dp(batch, phases)
+        with _Phase(phases, "classify_s", "repro.engine.classify"):
+            algs = select_algorithm_batch(batch)
+            groups: dict = {}
+            for b, alg in enumerate(algs):
+                key = "selection" if alg in ("marin", "marco") else alg
+                groups.setdefault(key, []).append(b)
         if set(groups) == {"dp"}:
-            return self._dispatch_dp(batch)
+            return self._dispatch_dp(batch, phases)
+
+        def take(key):
+            with _Phase(phases, "pack_s", "repro.engine.pack"):
+                return self._take(batch, groups[key])
+
         parts = []
         # DP first: its executable is the slowest, let it compute while the
         # host parts run
         if "dp" in groups:
-            parts.append((groups["dp"], self._dispatch_dp(self._take(batch, groups["dp"]))))
+            parts.append((groups["dp"], self._dispatch_dp(take("dp"), phases)))
         if "selection" in groups:
             parts.append(
-                (groups["selection"], self._dispatch_selection(self._take(batch, groups["selection"])))
+                (groups["selection"], self._dispatch_selection(take("selection"), phases))
             )
         for alg in ("mardecun", "mardec"):
             if alg in groups:
-                parts.append((groups[alg], self._host_part(self._take(batch, groups[alg]), alg)))
+                parts.append((groups[alg], self._host_part(take(alg), alg)))
         return RegimeSplitHandle(batch.B, batch.n, parts)
 
     def solve(self, problems, split_regimes: bool = False) -> np.ndarray:

@@ -32,22 +32,30 @@ Pipeline (DESIGN.md §14)::
     pure-DP flushes, ``k_last``/``objectives``) out of the shared batched
     handle; handle materialization is thread-safe (lock-guarded in
     ``core/sweep.py``), so many requesters can drain one flush at once.
+  * **Observability**: each request and each flush gets an integer id, and
+    every stage above is a named span (``repro.serve.*``, and the engine's
+    ``repro.engine.*`` inside each flush) carrying the ids as ``request=`` /
+    ``flush=`` metadata, recorded on the profiler's clock only while a
+    profile runs; :meth:`SchedulerService.stats` sums the stages' host time
+    whether or not one does (DESIGN.md §14, "Observability").
 """
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.pareto import assemble_frontier, candidate_deadlines, tightened_instances
 from ..core.problem import Problem, ProblemBatch, total_cost
 from ..core.resilience import CircuitBreaker, RetryPolicy, is_transient
 from ..core.scheduler import _schedule
-from ..core.sweep import SweepEngine, _next_pow2, default_engine
+from ..core.sweep import DISPATCH_PHASES, SweepEngine, _next_pow2, default_engine
 from .coalesce import coalesce_key, combine_batches, pow2_ladder, warm_batch
 
 __all__ = [
@@ -78,9 +86,9 @@ class ScheduleFuture:
     (for ``split_regimes=False`` requests) :meth:`k_last` demux the same
     per-request views out of the batched handle with no extra dispatch.
 
-    ``submitted_at`` / ``completed_at`` are ``time.monotonic()`` stamps set
-    by the service (completion is stamped when the completer thread lands
-    the flush) — the served-latency telemetry ``bench_serve.py`` reports.
+    ``submitted_at`` / ``completed_at`` are the service's ``time.monotonic()``
+    stamps of admission and of the completer landing the flush: the same
+    stamps its ``queue_wait_s`` and ``land_s`` counters are measured from.
     """
 
     def __init__(self, rows: int, n: int, squeeze: bool):
@@ -230,12 +238,29 @@ class _DegradedHandle:
 
 
 class _Request:
-    __slots__ = ("batch", "future", "t_submit")
+    __slots__ = ("rid", "batch", "future", "t_submit")
 
-    def __init__(self, batch: ProblemBatch, future: ScheduleFuture, t_submit: float):
+    def __init__(self, rid: int, batch: ProblemBatch, future: ScheduleFuture, t_submit: float):
+        self.rid = rid  # the request's id in its spans
         self.batch = batch
         self.future = future
-        self.t_submit = t_submit
+        self.t_submit = t_submit  # admission: the future's submitted_at
+
+
+class _Flush:
+    """One coalesced dispatch, from the coalescer taking its requests to the
+    completer landing it."""
+
+    __slots__ = ("fid", "reqs", "split", "trigger", "t_take", "combined", "slices", "t_launched")
+
+    def __init__(self, fid: int, reqs, split: bool, trigger: str, t_take: float):
+        self.fid = fid  # the flush's id in its spans
+        self.reqs = reqs
+        self.split = split
+        self.trigger = trigger
+        self.t_take = t_take
+        self.combined = self.slices = None
+        self.t_launched = None  # the engine dispatch returned (None: degraded)
 
 
 class SchedulerService:
@@ -292,6 +317,8 @@ class SchedulerService:
         self._pending_rows = 0  # admitted, not yet flushed
         self._inflight_rows = 0  # admitted, not yet completed (the bound)
         self._closed = False
+        self._request_ids = itertools.count()
+        self._flush_ids = itertools.count()
         self._stats = {
             "requests": 0,
             "rows": 0,
@@ -307,6 +334,12 @@ class SchedulerService:
             "flush_failures": 0,
             "degraded_flushes": 0,
             "degraded_rows": 0,
+            "submit_s": 0.0,
+            "queue_wait_s": 0.0,
+            "taken_requests": 0,
+            "land_s": 0.0,
+            "landed_flushes": 0,
+            **dict.fromkeys(DISPATCH_PHASES, 0),
         }
         self._done_q: queue.SimpleQueue = queue.SimpleQueue()
         self._coalescer = threading.Thread(
@@ -334,51 +367,65 @@ class SchedulerService:
         bound is full; ``timeout`` seconds later raises
         :class:`ServiceOverloaded` instead.
         """
-        squeeze = isinstance(problems, Problem)
-        if squeeze:
-            batch = ProblemBatch.from_problems([problems])
-        elif isinstance(problems, ProblemBatch):
-            batch = problems
-        else:
-            batch = ProblemBatch.from_problems(problems)
-        batch.validate()
-        key = coalesce_key(batch, split_regimes)  # cheap numpy, outside the lock
-        future = ScheduleFuture(batch.B, batch.n, squeeze)
-        deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cond:
-            while True:
+        t0 = time.monotonic()
+        rid = next(self._request_ids)
+        with TraceAnnotation("repro.serve.submit", request=rid):
+            with TraceAnnotation("repro.serve.pack", request=rid):
+                squeeze = isinstance(problems, Problem)
+                if squeeze:
+                    batch = ProblemBatch.from_problems([problems])
+                elif isinstance(problems, ProblemBatch):
+                    batch = problems
+                else:
+                    batch = ProblemBatch.from_problems(problems)
+                batch.validate()
+                key = coalesce_key(batch, split_regimes)  # cheap numpy, outside the lock
+                future = ScheduleFuture(batch.B, batch.n, squeeze)
+            with self._cond:
+                if not (self._closed or self._has_room(batch.B)):
+                    with TraceAnnotation("repro.serve.admit", request=rid):
+                        self._wait_for_room(batch.B, timeout)
                 if self._closed:
                     raise ServiceClosed("submit() after close()")
-                if (
-                    self._inflight_rows + batch.B <= self.max_pending
-                    or self._inflight_rows == 0  # oversize request, alone
-                ):
-                    break
-                remaining = None if deadline is None else deadline - time.monotonic()
-                if remaining is not None and remaining <= 0:
-                    self._stats["rejected"] += 1
-                    raise ServiceOverloaded(
-                        f"admission queue full ({self._inflight_rows}/"
-                        f"{self.max_pending} rows in flight) past timeout"
-                    )
-                self._cond.wait(remaining)
-            t_now = time.monotonic()
-            future.submitted_at = t_now
-            was_idle = not self._pending
-            bucket = self._pending.setdefault(key, [])
-            bucket.append(_Request(batch, future, t_now))
-            self._pending_rows += batch.B
-            self._inflight_rows += batch.B
-            self._stats["requests"] += 1
-            self._stats["rows"] += batch.B
-            # Wake the coalescer only when this submit changes its schedule:
-            # a new deadline (queue was idle) or a size-ripe bucket. A later
-            # arrival never shortens an existing delay deadline, so skipping
-            # the notify here avoids a context switch per request on the
-            # saturated path (the coalescer wakes on its own timer).
-            if was_idle or sum(r.batch.B for r in bucket) >= self.max_batch:
-                self._cond.notify_all()
+                t_now = time.monotonic()
+                future.submitted_at = t_now
+                was_idle = not self._pending
+                bucket = self._pending.setdefault(key, [])
+                bucket.append(_Request(rid, batch, future, t_now))
+                self._pending_rows += batch.B
+                self._inflight_rows += batch.B
+                self._stats["requests"] += 1
+                self._stats["rows"] += batch.B
+                # Wake the coalescer only when this submit changes its
+                # schedule: a new deadline (queue was idle) or a size-ripe
+                # bucket. A later arrival never shortens an existing delay
+                # deadline, so skipping the notify here avoids a context
+                # switch per request on the saturated path (the coalescer
+                # wakes on its own timer).
+                if was_idle or sum(r.batch.B for r in bucket) >= self.max_batch:
+                    self._cond.notify_all()
+                self._stats["submit_s"] += time.monotonic() - t0
         return future
+
+    def _has_room(self, rows: int) -> bool:
+        return (
+            self._inflight_rows + rows <= self.max_pending
+            or self._inflight_rows == 0  # oversize request, alone
+        )
+
+    def _wait_for_room(self, rows: int, timeout: Optional[float]) -> None:
+        """Blocks (lock held) until admission has room for ``rows`` or the
+        service closes; raises :class:`ServiceOverloaded` past ``timeout``."""
+        deadline = None if timeout is None else time.monotonic() + timeout
+        while not (self._closed or self._has_room(rows)):
+            remaining = None if deadline is None else deadline - time.monotonic()
+            if remaining is not None and remaining <= 0:
+                self._stats["rejected"] += 1
+                raise ServiceOverloaded(
+                    f"admission queue full ({self._inflight_rows}/"
+                    f"{self.max_pending} rows in flight) past timeout"
+                )
+            self._cond.wait(remaining)
 
     def submit_frontier(
         self,
@@ -478,21 +525,70 @@ class SchedulerService:
                 f"entries would be evicted before serving. Use "
                 f"SweepEngine(max_entries>={len(planned)}) or warm fewer buckets."
             )
+        def solve(batch, split: bool) -> float:  # its compile seconds
+            handle = self.engine.dispatch(batch, split_regimes=split)
+            handle.result()
+            return _phases(handle).get("compile_s", 0.0)
+
         before = self.engine.cache_stats()["compiles"]
-        for n, T, W in specs:
-            for B in sizes:
-                wb = warm_batch(n, T, W, B, regime="arbitrary")
-                self.engine.dispatch(wb, split_regimes=split_regimes).result()
-                if split_regimes:
-                    mono = warm_batch(n, T, W, B, regime="increasing")
-                    self.engine.dispatch(mono, split_regimes=True).result()
+        compile_s = 0.0
+        with TraceAnnotation("repro.serve.warm"):
+            for n, T, W in specs:
+                for B in sizes:
+                    compile_s += solve(warm_batch(n, T, W, B, regime="arbitrary"), split_regimes)
+                    if split_regimes:
+                        compile_s += solve(warm_batch(n, T, W, B, regime="increasing"), True)
         traced = self.engine.cache_stats()["compiles"] - before
         with self._cond:
             self._stats["warmed_executables"] += traced
+            self._stats["compile_s"] += compile_s
         return traced
 
     def stats(self) -> dict:
-        """Service counters plus live queue depths (rows)."""
+        """Service counters plus live queue depths (rows).
+
+        Counts: ``requests`` / ``rows`` admitted, ``completed_requests``,
+        ``flushes`` (engine dispatches, retries included) and
+        ``flushed_rows``, flushes by trigger (``size_flushes``,
+        ``delay_flushes``, ``close_flushes``), ``rejected`` submits,
+        ``warmed_executables``, and the failure paths' ``retries``,
+        ``flush_failures``, ``degraded_flushes``, ``degraded_rows``.
+
+        Host time, in seconds summed over events, each with the count that
+        divides it into a mean (``time.monotonic`` / ``perf_counter``;
+        always on, a few stamps per request):
+
+        * ``submit_s`` / ``requests``: the whole :meth:`submit` call,
+          admission waits included;
+        * ``queue_wait_s`` / ``taken_requests``: admission to the coalescer
+          taking the request into a flush (the ``max_delay_s`` hold and any
+          wait behind the coalescer);
+        * ``dispatch_s`` / ``flushes``: the engine call, split into
+          ``classify_s`` (regime split, ``split_regimes`` flushes only),
+          ``pack_s`` (validation, padding, packing, small input arrays) and
+          ``launch_s`` (the executable's enqueue); see
+          :meth:`~repro.core.sweep.SweepEngine.dispatch`;
+        * ``land_s`` / ``landed_flushes``: the engine call returning to the
+          flush's futures resolved (the wait behind earlier device work, the
+          device time, the transfer back and the demux);
+        * ``compile_s``: this service's dispatches, :meth:`warm` included,
+          that traced and compiled (the engine's own total is
+          ``cache_stats()["compile_s"]``).
+
+        DP padding, as cell counts over this service's flushes:
+        ``dp_band_cells`` is the min-plus work the instances need,
+        ``(T' + 1) * (U_i - L_i + 1)`` summed over real rows and clients,
+        and ``dp_computed_cells`` what the DP executables compute over their
+        buckets, ``Bb * nb * (Tb + 1) * Wb`` per flush; their ratio is the
+        share of the kernel's cells that is not padding.
+
+        The same stages are spans on the profiler's clock while a profile
+        runs: ``repro.serve.submit`` (its ``pack``, and ``admit`` where
+        admission blocks), ``idle`` / ``hold`` on the coalescer,
+        ``flush`` with one ``take`` per request and the engine's
+        ``repro.engine.*`` spans inside, ``materialize`` / ``demux`` on the
+        completer, ``recover`` / ``degraded`` on the failure paths and
+        ``warm``, each with its ``request=`` / ``flush=`` ids."""
         with self._cond:
             out = dict(self._stats)
             out["pending_rows"] = self._pending_rows
@@ -540,9 +636,11 @@ class SchedulerService:
                         break
                     if self._pending:
                         oldest = min(rs[0].t_submit for rs in self._pending.values())
-                        self._cond.wait(max(oldest + self.max_delay_s - now, 0.0))
+                        with TraceAnnotation("repro.serve.hold"):
+                            self._cond.wait(max(oldest + self.max_delay_s - now, 0.0))
                     else:
-                        self._cond.wait()
+                        with TraceAnnotation("repro.serve.idle"):
+                            self._cond.wait()
                 now = time.monotonic()
                 for key in list(self._pending):
                     trigger = (
@@ -570,37 +668,59 @@ class SchedulerService:
                             self._pending[key] = queued[len(take) :]
                         self._pending_rows -= rows
                         self._stats[f"{trigger}_flushes"] += 1
-                        flushes.append((key, take))
+                        self._stats["queue_wait_s"] += sum(now - r.t_submit for r in take)
+                        self._stats["taken_requests"] += len(take)
+                        flushes.append(
+                            _Flush(next(self._flush_ids), take, key[3], trigger, now)
+                        )
                         if not self._closed:
                             break
                 drained = self._closed and not self._pending
                 self._cond.notify_all()
-            for key, reqs in flushes:
-                self._flush(key, reqs)
+            for fl in flushes:
+                self._flush(fl)
             if drained:
                 self._done_q.put(None)  # completer: nothing further is coming
                 return
 
-    def _flush(self, key, reqs) -> None:
+    def _flush(self, fl: _Flush) -> None:
         """ONE engine dispatch for a ripe bucket (async — the executable is
         launched, not materialized), handed to the completer. Failure
         handling (retry / breaker / degraded solve) runs on the completer
         thread so the coalescer's flush cadence never blocks on backoff."""
-        split = key[3]
-        combined, slices = combine_batches([r.batch for r in reqs])
-        if self.breaker is not None and not self.breaker.allow():
-            # breaker open: route straight to the degraded direct-solve path
-            self._done_q.put(("degraded", None, reqs, slices, combined, split))
-            return
-        try:
-            handle = self.engine.dispatch(combined, split_regimes=split)
-        except BaseException as e:
-            self._done_q.put(("failed", e, reqs, slices, combined, split))
-            return
+        with TraceAnnotation(
+            "repro.serve.flush",
+            flush=fl.fid,
+            rows=sum(r.batch.B for r in fl.reqs),
+            requests=len(fl.reqs),
+            trigger=fl.trigger,
+        ):
+            for r in fl.reqs:
+                wait_us = int((fl.t_take - r.t_submit) * 1e6)
+                with TraceAnnotation("repro.serve.take", request=r.rid, flush=fl.fid, wait_us=wait_us):
+                    pass
+            fl.combined, fl.slices = combine_batches([r.batch for r in fl.reqs])
+            if self.breaker is not None and not self.breaker.allow():
+                # breaker open: route straight to the degraded direct-solve path
+                self._done_q.put(("degraded", None, fl))
+                return
+            try:
+                handle = self.engine.dispatch(fl.combined, split_regimes=fl.split)
+            except BaseException as e:
+                self._done_q.put(("failed", e, fl))
+                return
+            fl.t_launched = time.monotonic()
+            self._count_flush(fl, handle)
+            self._done_q.put(("ok", handle, fl))
+
+    def _count_flush(self, fl: _Flush, handle) -> None:
+        """Counts a flush the engine dispatched, with the host time of its
+        dispatch phases (an engine that reports none adds none)."""
         with self._cond:
             self._stats["flushes"] += 1
-            self._stats["flushed_rows"] += combined.B
-        self._done_q.put(("ok", handle, reqs, slices, combined, split))
+            self._stats["flushed_rows"] += fl.combined.B
+            for k, v in _phases(handle).items():
+                self._stats[k] += v
 
     # ---- completer thread ----------------------------------------------
 
@@ -609,22 +729,27 @@ class SchedulerService:
             item = self._done_q.get()
             if item is None:
                 return
-            kind, payload, reqs, slices, combined, split = item
+            kind, payload, fl = item
             if kind == "ok":
                 try:
-                    X = payload.result()  # blocks until the device solve lands
+                    with TraceAnnotation("repro.serve.materialize", flush=fl.fid):
+                        X = payload.result()  # blocks until the device solve lands
                 except BaseException as e:
-                    self._recover_flush(reqs, slices, combined, split, e)
+                    self._recover_flush(fl, e)
                     continue
                 if self.breaker is not None:
                     self.breaker.record_success()
-                self._land(reqs, slices, payload, X)
+                self._land(fl, payload, X)
             elif kind == "failed":
-                self._recover_flush(reqs, slices, combined, split, payload)
+                self._recover_flush(fl, payload)
             else:  # "degraded": breaker was open at flush time
-                self._serve_degraded(reqs, slices, combined, split)
+                self._serve_degraded(fl)
 
-    def _recover_flush(self, reqs, slices, combined, split, exc) -> None:
+    def _recover_flush(self, fl: _Flush, exc) -> None:
+        with TraceAnnotation("repro.serve.recover", flush=fl.fid):
+            self._recover(fl, exc)
+
+    def _recover(self, fl: _Flush, exc) -> None:
         """A flush's engine attempt failed (at dispatch or materialization):
         retry transient errors under the policy, feed the breaker, and — with
         a breaker configured — serve exhausted-transient flushes from the
@@ -644,7 +769,8 @@ class SchedulerService:
                 with self._cond:
                     self._stats["retries"] += 1
                 try:
-                    handle = self.engine.dispatch(combined, split_regimes=split)
+                    handle = self.engine.dispatch(fl.combined, split_regimes=fl.split)
+                    fl.t_launched = time.monotonic()
                     X = handle.result()
                 except BaseException as e:
                     exc = e
@@ -657,23 +783,27 @@ class SchedulerService:
                     continue
                 if self.breaker is not None:
                     self.breaker.record_success()
-                with self._cond:
-                    self._stats["flushes"] += 1
-                    self._stats["flushed_rows"] += combined.B
-                self._land(reqs, slices, handle, X)
+                self._count_flush(fl, handle)
+                self._land(fl, handle, X)
                 return
+        fl.t_launched = None  # no engine dispatch landed
         if is_transient(exc) and self.breaker is not None:
-            self._serve_degraded(reqs, slices, combined, split)
+            self._serve_degraded(fl)
         else:
-            self._abort(reqs, exc)
+            self._abort(fl.reqs, exc)
 
-    def _serve_degraded(self, reqs, slices, combined, split) -> None:
+    def _serve_degraded(self, fl: _Flush) -> None:
+        with TraceAnnotation("repro.serve.degraded", flush=fl.fid):
+            self._solve_degraded(fl)
+
+    def _solve_degraded(self, fl: _Flush) -> None:
         """The circuit breaker's fallback: solve every instance of the flush
         with the host algorithms (``auto`` regime dispatch for split flushes,
         the reference DP otherwise) — engine-free, slower, but bit-identical
         schedules (asserted in tests/test_service_resilience.py), so callers
         cannot tell a degraded flush from a served one except by latency and
         the absence of ``k_last``."""
+        combined, split = fl.combined, fl.split
         try:
             X = np.zeros((combined.B, combined.n), dtype=np.int64)
             obj = np.zeros(combined.B, dtype=np.float64)
@@ -686,27 +816,40 @@ class SchedulerService:
                 )
                 obj[b] = total_cost(p, x) - fixed  # 0-lower-limit convention
         except BaseException as e:
-            self._abort(reqs, e)
+            self._abort(fl.reqs, e)
             return
         with self._cond:
             self._stats["degraded_flushes"] += 1
             self._stats["degraded_rows"] += combined.B
-        self._land(reqs, slices, _DegradedHandle(X, obj), X)
+        self._land(fl, _DegradedHandle(X, obj), X)
 
-    def _land(self, reqs, slices, handle, X) -> None:
-        t_done = time.monotonic()
-        for r, (lo, hi) in zip(reqs, slices):
-            # each request sees only ITS rows, trimmed to its own n
-            r.future._resolve(X[lo:hi, : r.batch.n].copy(), handle, lo, hi, t_done)
-        self._retire(reqs)
+    def _land(self, fl: _Flush, handle, X) -> None:
+        with TraceAnnotation("repro.serve.demux", flush=fl.fid):
+            t_done = time.monotonic()
+            for r, (lo, hi) in zip(fl.reqs, fl.slices):
+                # each request sees only ITS rows, trimmed to its own n
+                r.future._resolve(X[lo:hi, : r.batch.n].copy(), handle, lo, hi, t_done)
+            self._retire(fl.reqs, None if fl.t_launched is None else t_done - fl.t_launched)
 
     def _abort(self, reqs, exc: BaseException) -> None:
         for r in reqs:
             r.future._fail(exc)
         self._retire(reqs)
 
-    def _retire(self, reqs) -> None:
+    def _retire(self, reqs, land_s: Optional[float] = None) -> None:
+        """Releases the requests' admission rows; ``land_s`` is the landing
+        time of an engine-served flush."""
         with self._cond:
             self._inflight_rows -= sum(r.batch.B for r in reqs)
             self._stats["completed_requests"] += len(reqs)
+            if land_s is not None:
+                self._stats["land_s"] += land_s
+                self._stats["landed_flushes"] += 1
             self._cond.notify_all()  # wake producers blocked on admission
+
+
+def _phases(handle) -> dict:
+    """The host-time phases an engine dispatch reports on its handle
+    (:data:`~repro.core.sweep.DISPATCH_PHASES`); empty for an engine that
+    reports none."""
+    return getattr(handle, "phases", {})

@@ -110,6 +110,7 @@ def test_cached_solve_bit_identical_to_uncached(seed):
     np.testing.assert_array_equal(X2, solve_schedule_dp_batch(probs2))
     s = eng.cache_stats()
     per_bucket = s.pop("per_bucket_hits")
+    assert s.pop("compile_s") > 0  # the one call that traced and compiled
     assert s == {
         "hits": 1,
         "misses": 1,

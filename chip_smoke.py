@@ -2,10 +2,12 @@
 """Drives the planner's main path once on a TPU, through the entry points a
 user calls, and checks every answer against the repo's plain references.
 
-    python chip_smoke.py [--seed 0]    # one chip: five phases
+    python chip_smoke.py [--seed 0]    # one chip: six phases
     python chip_smoke.py --chips 4     # both sharded engines vs one chip
 
-One chip runs five phases: a cross-silo DP batch and a cross-device
+One chip runs six phases: the min-plus row update alone at the cross-silo
+shape, checked against the numpy DP and timed warm at ``B = 1`` and ``B = 8``;
+a cross-silo DP batch and a cross-device
 monotone batch through ``Solver.solve``, a fleet through
 ``Solver.solve_fleet``, two submitter threads through one
 ``SchedulerService``, and a three-round ``run_campaign``. ``--chips 4`` runs
@@ -33,10 +35,12 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
 
 from repro.core import (  # noqa: E402
+    ItemClass,
     Problem,
     Solver,
     SweepEngine,
     marin,
+    mc2mkp_matrices,
     random_problem,
     solve_schedule_dp,
     total_cost,
@@ -45,6 +49,8 @@ from repro.core import (  # noqa: E402
 
 # the cross-silo cell: tens of organisations, thousands of batches each
 SILO = dict(B=8, n=32, T=16_000, u_max=1023)
+# one DP row update at the cross-silo bucket, (B, T + 1, W), alone and batched
+MINPLUS_SHAPES = ((1, 16_385, 1024), (8, 16_385, 1024))
 # the cross-device monotone cell
 MONO = dict(B=4, n=4096, u_max=63)
 FLEET_N = 16_384
@@ -91,6 +97,42 @@ def compiles(engine) -> int:
 # ---------------------------------------------------------------------------
 # one-chip phases; each returns (shapes, engine compiles)
 # ---------------------------------------------------------------------------
+
+
+def phase_minplus(seed: int, shapes):
+    import jax
+
+    from repro.kernels import BIG, minplus_step_batch
+
+    rng = np.random.default_rng(seed + 5)
+    timed = []
+    for B, Tp, W in shapes:
+        # integer entries, so every float32 sum is exact and equals the float64 DP's
+        kprev = rng.integers(0, 1 << 20, size=(B, Tp)).astype(np.float32)
+        kprev[rng.random((B, Tp)) < 0.2] = BIG
+        kprev[:, 0] = 0
+        cost = rng.integers(0, 50_000, size=(B, W)).astype(np.float32)
+        cost[rng.random((B, W)) < 0.05] = BIG
+        val, arg = (np.asarray(a) for a in minplus_step_batch(kprev, cost, backend="pallas_tpu"))
+        for b in range(B):
+            # the numpy DP's Z_2 over two classes: every t at cost kprev[t], then the band
+            fin = np.flatnonzero(kprev[b] < BIG)
+            band = np.where(cost[b] < BIG, cost[b].astype(np.float64), np.inf)
+            K, I = mc2mkp_matrices(
+                [ItemClass(fin, kprev[b, fin]), ItemClass(np.arange(W), band)], Tp - 1)
+            ok = np.isfinite(K[1])
+            where = f"({B}, {Tp}, {W}) row {b}"
+            check(np.array_equal(ok, val[b] < BIG), f"{where}: feasibility differs")
+            check(np.array_equal(val[b, ok], K[1, ok]), f"{where}: values differ")
+            check(np.array_equal(arg[b, ok], I[1, ok]), f"{where}: argmins differ")
+        on_chip = jax.device_put((kprev, cost))
+        times = []
+        for _ in range(10):
+            t0 = time.perf_counter()
+            jax.block_until_ready(minplus_step_batch(*on_chip, backend="pallas_tpu"))
+            times.append(time.perf_counter() - t0)
+        timed.append(f"({B}, {Tp}, {W}) {1e3 * float(np.median(times)):.3f} ms")
+    return "warm row update from device arrays, host clock: " + ", ".join(timed), 0
 
 
 def phase_cross_silo(seed: int, B: int, n: int, T: int, u_max: int, backend: str):
@@ -326,6 +368,7 @@ def main(argv=None) -> int:
                   SILO["u_max"])
     else:
         used = devices[:1]
+        run_phase("minplus", phase_minplus, args.seed, MINPLUS_SHAPES)
         run_phase("cross_silo", phase_cross_silo, args.seed, *SILO.values(), "pallas_tpu")
         run_phase("monotone", phase_monotone, args.seed, *MONO.values())
         run_phase("fleet", phase_fleet, args.seed, FLEET_N)

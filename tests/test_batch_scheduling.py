@@ -175,6 +175,7 @@ def _random_rows(rng, B, Tp, W):
     (1, 64, 16, 128),
     (4, 300, 33, 128),
     pytest.param(8, 255, 64, 128, marks=pytest.mark.slow),  # larger interpret-mode sweep
+    (3, 1025, 130, 128),  # each element folded onto eight segments
 ])
 def test_batched_pallas_matches_batched_ref(B, Tp, W, BT):
     from repro.kernels import minplus_pallas_batch, minplus_step_ref_batch
@@ -183,8 +184,10 @@ def test_batched_pallas_matches_batched_ref(B, Tp, W, BT):
     k, c = _random_rows(rng, B, Tp, W)
     rv, ri = minplus_step_ref_batch(k, c)
     pv, pi = minplus_pallas_batch(k, c, BT=BT, interpret=True)
-    np.testing.assert_allclose(np.asarray(pv), np.asarray(rv), rtol=1e-6)
-    # argmin: reconstructed value must equal the min (ties may differ)
+    # the same float32 sums in the same band order: bit-identical, ties included
+    np.testing.assert_array_equal(np.asarray(pv), np.asarray(rv))
+    np.testing.assert_array_equal(np.asarray(pi), np.asarray(ri))
+    # argmin: reconstructed value must equal the min
     pi = np.asarray(pi)
     src = np.arange(Tp)[None, :] - pi
     ok = src >= 0

@@ -193,20 +193,36 @@ def test_resolve_backend_interprets_only_on_cpu(monkeypatch):
 
 
 def test_tpu_tuned_bt_respects_vmem_budget():
-    # short rows get a tile of whole lane tiles that does not overshoot the
-    # row; longer rows get the default tile; rows whose double-buffered
-    # padded row does not fit VMEM raise instead of reaching the compiler
+    # the row is folded onto eight sublanes: short segments get a tile of
+    # whole lane tiles that does not overshoot the segment; longer ones get
+    # the default tile; rows whose double-buffered (8, Wp + S) halo block
+    # does not fit VMEM raise instead of reaching the compiler
     assert tpu_tuned_bt(100, 512) == 128
-    assert tpu_tuned_bt(4096, 512) == 1024
+    assert tpu_tuned_bt(4096, 512) == 512
+    assert tpu_tuned_bt(16_385, 1024) == 1024
     assert tpu_tuned_bt(60_000, 512) == 1024
     with pytest.raises(ValueError, match="VMEM"):
         tpu_tuned_bt(4_000_000, 1024)
     for Tp, W in [(1, 1), (1000, 100), (100_000, 2048), (1_000_000, 512)]:
         bt = tpu_tuned_bt(Tp, W)
         assert bt % 128 == 0  # f32 lane granularity of the output tiles
-        tpad = -(-Tp // bt) * bt
+        seg = -(-(-(-Tp // 8)) // bt) * bt
         wp = -(-W // 128) * 128
-        assert 2 * 4 * (wp + tpad) + 2 * 2 * 4 * bt <= 16 * 2**20
+        assert 2 * 4 * 8 * (wp + seg) + 2 * 2 * 4 * 8 * bt <= 16 * 2**20
+
+
+@pytest.mark.parametrize("W", [1, 64, 700, 1024])
+def test_tpu_tuned_bt_fold_makes_no_row_slower(W):
+    # against the unfolded layout, one (1, BT) tile of min(1024, Tp) lanes:
+    # a band step loads no more vregs, and a row takes no more band steps
+    for Tp in [1, 2, 127, 128, 129, 1000, 1024, 1025, 2049, 4097, 8192, 8193,
+               9216, 16_385, 100_000, 1_000_000]:
+        bt = tpu_tuned_bt(Tp, W)
+        seg = -(-(-(-Tp // 8)) // bt) * bt
+        unfolded_bt = min(1024, -(-Tp // 128) * 128)
+        assert 8 * seg >= Tp
+        assert bt // 128 + 1 <= unfolded_bt // 128 + 1  # vregs in a (8 | 1, BT + 128) load
+        assert seg // bt <= -(-Tp // unfolded_bt)  # output tiles, each a band walk
 
 
 # ---------------------------------------------------------------------------

@@ -9,7 +9,13 @@ import pytest
 
 from repro.core import Problem, solve_schedule_dp, total_cost
 from repro.core.jax_dp import solve_schedule_dp_jax
-from repro.kernels import BIG, minplus_pallas, minplus_step_ref
+from repro.kernels import (
+    BIG,
+    minplus_pallas,
+    minplus_pallas_batch,
+    minplus_step_ref,
+    minplus_step_ref_batch,
+)
 
 
 def numpy_minplus(kprev, cost):
@@ -56,6 +62,12 @@ def test_ref_matches_numpy(Tp, W):
     (1000, 511, 128),
     pytest.param(2048, 1024, 1024, marks=pytest.mark.slow),  # big interpret-mode sweep
     (33, 33, 1024),  # tile larger than the row
+    # rows that do not fill the eight folded segments of whole tiles
+    (1, 1, 128),
+    (129, 64, 128),
+    (1025, 130, 128),
+    (2 * 1024 + 1, 256, 256),
+    (300, 700, 128),  # band wider than a segment: the halo spans several segments
 ])
 def test_pallas_matches_ref(Tp, W, BT):
     rng = np.random.default_rng(Tp + W + BT)
@@ -63,9 +75,11 @@ def test_pallas_matches_ref(Tp, W, BT):
     cost = rng.uniform(0, 10, size=W).astype(np.float32)
     cost[W // 2 :] += np.where(rng.random(W - W // 2) < 0.2, float(BIG), 0.0).astype(np.float32)
     cost = np.minimum(cost, float(BIG))
-    ref_v, _ = minplus_step_ref(kprev, cost)
+    ref_v, ref_i = minplus_step_ref(kprev, cost)
     pal_v, pal_i = minplus_pallas(kprev, cost, BT=BT, interpret=True)
-    np.testing.assert_allclose(np.asarray(pal_v), np.asarray(ref_v), rtol=1e-6)
+    # the same float32 sums in the same band order: bit-identical to the oracle
+    np.testing.assert_array_equal(np.asarray(pal_v), np.asarray(ref_v))
+    np.testing.assert_array_equal(np.asarray(pal_i), np.asarray(ref_i))
     # argmin consistency: reconstruct value from index
     pi = np.asarray(pal_i)
     src = np.arange(Tp) - pi
@@ -73,6 +87,24 @@ def test_pallas_matches_ref(Tp, W, BT):
     recon = np.where(ok, kprev[np.maximum(src, 0)] + cost[pi], float(BIG))
     recon = np.minimum(recon, float(BIG))
     np.testing.assert_allclose(recon, np.asarray(ref_v), rtol=1e-6)
+
+
+@pytest.mark.parametrize("B,Tp,W,BT", [
+    (1, 200, 64, 128),
+    (3, 1025, 130, 128),
+    (3, 300, 700, 128),
+])
+def test_pallas_ties_resolve_to_first_minimum(B, Tp, W, BT):
+    # constant cost rows over a previous row of few distinct values: most
+    # outputs tie across many band steps, and each must take the first
+    rng = np.random.default_rng(B * 1000 + Tp + W)
+    kprev = rng.integers(0, 3, size=(B, Tp)).astype(np.float32)
+    kprev[rng.random((B, Tp)) < 0.2] = float(BIG)
+    cost = np.repeat(rng.integers(0, 5, size=(B, 1)), W, axis=1).astype(np.float32)
+    ref_v, ref_i = minplus_step_ref_batch(kprev, cost)
+    pal_v, pal_i = minplus_pallas_batch(kprev, cost, BT=BT, interpret=True)
+    np.testing.assert_array_equal(np.asarray(pal_v), np.asarray(ref_v))
+    np.testing.assert_array_equal(np.asarray(pal_i), np.asarray(ref_i))
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64, np.int32])

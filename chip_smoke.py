@@ -2,13 +2,16 @@
 """Drives the planner's main path once on a TPU, through the entry points a
 user calls, and checks every answer against the repo's plain references.
 
-    python chip_smoke.py [--seed 0]    # one chip: six phases
+    python chip_smoke.py [--seed 0]    # one chip: seven phases
     python chip_smoke.py --chips 4     # both sharded engines vs one chip
 
-One chip runs six phases: the min-plus row update alone at the cross-silo
+One chip runs seven phases: the min-plus row update alone at the cross-silo
 shape, checked against the numpy DP and timed warm at ``B = 1`` and ``B = 8``;
 a cross-silo DP batch and a cross-device
-monotone batch through ``Solver.solve``, a fleet through
+monotone batch through ``Solver.solve``, one cross-device round with
+per-phone uploads through the engine's regime split at the DP bucket
+``(n, T, W) = (4096, 65536, 64)``, checked against the benchmark's exact
+integer reference, a fleet through
 ``Solver.solve_fleet``, two submitter threads through one
 ``SchedulerService``, and a three-round ``run_campaign``. ``--chips 4`` runs
 only the sharded DP: the batch-axis mesh and the class-axis ring, each
@@ -53,6 +56,8 @@ SILO = dict(B=8, n=32, T=16_000, u_max=1023)
 MINPLUS_SHAPES = ((1, 16_385, 1024), (8, 16_385, 1024))
 # the cross-device monotone cell
 MONO = dict(B=4, n=4096, u_max=63)
+# the cross-device uplink cell: the whole population, T' = 65% of its batches
+UPLINK_SHARE = 0.65
 FLEET_N = 16_384
 # served traffic: (n, T, u_max) of each of the three bucket shapes
 SERVED_SHAPES = ((16, 1_500, 255), (32, 6_000, 511), (8, 3_000, 1023))
@@ -165,6 +170,50 @@ def phase_monotone(seed: int, B: int, n: int, u_max: int):
         check(np.array_equal(sol.schedules[b], ref), f"instance {b}: schedule differs from marin")
         check(sol.objectives[b] == total_cost(p, ref), f"instance {b}: objective differs")
     return f"B={B} n={n} W={u_max + 1} T~{problems[0].T}", compiles(solver.engine)
+
+
+def phase_uplink(seed: int, share: float):
+    """One round over the 3,550 FEMNIST phones of ``chipbench``'s uplink
+    deployment: every phone that takes part pays its upload, so the regime
+    split sends the round to the DP."""
+    root = os.path.dirname(os.path.abspath(__file__))
+    if root not in sys.path:  # the benchmark's family and reference, for this phase
+        sys.path.append(root)
+    from chipbench.traffic import load
+    from repro.core.jax_dp import argmin_dtype
+
+    here = os.path.join(root, "chipbench")
+    with open(os.path.join(here, "configs", "xdevice-uplink.json")) as f:
+        config = json.load(f)
+    upper, tables = load("families", "uplink", here).prepare(config)
+    rng = np.random.default_rng(seed + 6)
+    idx = np.sort(rng.permutation(len(upper)))  # every phone eligible
+    p = Problem(T=int(share * upper[idx].sum()), lower=np.zeros(len(idx), np.int64),
+                upper=upper[idx], cost_tables=tuple(tables[i] for i in idx))
+    engine = SweepEngine()
+    handle = engine.dispatch([p], split_regimes=True)
+    x = handle.result()[0]
+    check(handle.phases["dp_rows"] == 4096 and compiles(engine) == 1,
+          "the uplink round did not take one DP executable of 4096 rows")
+    validate_schedule(p, x)
+    t0 = time.perf_counter()
+    _, optimum = load("references", "uplink_dp", here).solve(p.T, p.lower, p.upper,
+                                                              p.cost_tables)
+    ref_s = time.perf_counter() - t0
+    gap = total_cost(p, x) - optimum
+    limit = config["check"]["limits"]["cost_gap_mj"]
+    check(0 <= gap <= limit, f"served cost {gap} mJ over the exact optimum, limit {limit}")
+    warm = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        engine.dispatch([p], split_regimes=True).result()
+        warm.append(time.perf_counter() - t0)
+    slab = f"{np.dtype(argmin_dtype(64)).name}[4096, 1, 65537]"
+    print(f"  uplink gap={gap!r} mJ, argmin matrix {slab} "
+          f"(rows {handle.phases['dp_rows']}, live {handle.phases['dp_live_rows']}), "
+          f"warm solve {1e3 * float(np.median(warm)):.3f} ms host clock, "
+          f"exact reference {ref_s:.2f} s")
+    return f"n={p.n} T={p.T} W=64 bucket (4096, 65536, 64)", compiles(engine)
 
 
 def phase_fleet(seed: int, n: int):
@@ -371,6 +420,7 @@ def main(argv=None) -> int:
         run_phase("minplus", phase_minplus, args.seed, MINPLUS_SHAPES)
         run_phase("cross_silo", phase_cross_silo, args.seed, *SILO.values(), "pallas_tpu")
         run_phase("monotone", phase_monotone, args.seed, *MONO.values())
+        run_phase("uplink", phase_uplink, args.seed, UPLINK_SHARE)
         run_phase("fleet", phase_fleet, args.seed, FLEET_N)
         run_phase("served", phase_served, args.seed, SERVED_SHAPES, SERVED_REQUESTS)
         run_phase("campaign", phase_campaign, args.seed, *CAMPAIGN.values())

@@ -8,7 +8,9 @@ matrix, fused into the SAME jitted program as the class scan
 (:func:`solve_fused_batch_jax`): one dispatch returns only the ``(B, n)``
 schedules plus the final DP row ``K_last`` — the ``(n, B, T+1)`` argmin
 matrix never crosses a program boundary, so nothing bigger than the answer
-is ever transferred. This is what runs server-side every FL round when
+is ever transferred. Argmins are band offsets ``j < W``, so the matrix is
+stored in the narrowest integer dtype that holds them
+(:func:`argmin_dtype`). This is what runs server-side every FL round when
 schedules are recomputed from refreshed energy estimates.
 
 Inputs are the 0-lower-limit equivalent instance (Section 5.2) as dense
@@ -32,6 +34,7 @@ from .problem import (
 )
 
 __all__ = [
+    "argmin_dtype",
     "solve_schedule_dp_jax",
     "solve_schedule_dp_batch",
     "solve_fused_batch_jax",
@@ -104,16 +107,31 @@ def solve_schedule_dp_jax(problem: Problem, backend: str = "auto") -> np.ndarray
 # ---------------------------------------------------------------------------
 
 
+def argmin_dtype(W: int):
+    """The dtype of the argmin matrix for band width ``W``. Its entries are
+    band offsets ``0 <= j < W``: int8 holds them up to ``W = 128``, int16 up
+    to ``W = 32768``, int32 beyond. At the cross-device bucket ``(B, n, T, W)
+    = (8, 4096, 65536, 64)`` int8 keeps the matrix at 2.1 GB, not 8.6."""
+    W = int(W)
+    if W <= 1 << 7:
+        return jnp.int8
+    if W <= 1 << 15:
+        return jnp.int16
+    return jnp.int32
+
+
 def _dp_scan_from(k0: jnp.ndarray, costs: jnp.ndarray, backend: str = "ref"):
     """Continues the class scan from an arbitrary DP row ``k0 (B, T+1)`` over
     the classes in ``costs (B, n, W)``. Factored out of
     :func:`_dp_tables_batch` so the ring-sharded solver (below) can run each
     device's local classes through the IDENTICAL op sequence — bit-identity
-    of the sharded path reduces to handing the row around the ring."""
+    of the sharded path reduces to handing the row around the ring. Each
+    argmin row is narrowed to :func:`argmin_dtype` as it is stacked."""
+    narrow = argmin_dtype(costs.shape[2])
 
     def step(krow, cost_i):
         kout, iout = minplus_step_batch(krow, cost_i, backend=backend)
-        return kout, iout
+        return kout, iout.astype(narrow)
 
     # scan over the class axis: xs must lead with n
     return jax.lax.scan(step, k0, jnp.swapaxes(costs, 0, 1))
@@ -137,7 +155,8 @@ def dp_tables_batch_jax(costs: jnp.ndarray, T: int, backend: str = "ref"):
       T: static row width — the max ``T'`` across the batch; rows are shared,
         per-instance workloads only enter at backtracking via ``t_star``.
 
-    Returns (K_last ``(B, T+1)``, I ``(n, B, T+1)``). Production solves use
+    Returns (K_last ``(B, T+1)``, I ``(n, B, T+1)`` in
+    :func:`argmin_dtype` of ``W``). Production solves use
     :func:`solve_fused_batch_jax` instead, which never lets ``I`` escape the
     program; this two-dispatch path remains as the oracle the fused solver
     is validated against.
@@ -146,14 +165,17 @@ def dp_tables_batch_jax(costs: jnp.ndarray, T: int, backend: str = "ref"):
 
 
 def _backtrack_batch(I: jnp.ndarray, t_star: jnp.ndarray, T: int):
-    """Unjitted body of :func:`backtrack_batch_jax` (see above)."""
+    """Unjitted body of :func:`backtrack_batch_jax` (see above). The scan
+    walks ``I`` from its last class in place (``reverse=True``), so no
+    reversed copy of the argmin matrix is made; schedules are int32 whatever
+    the argmins' dtype."""
 
     def step(t, irow):  # t: (B,), irow: (B, T+1)
-        j = jnp.take_along_axis(irow, t[:, None].astype(jnp.int32), axis=1)[:, 0]
+        j = jnp.take_along_axis(irow, t[:, None], axis=1)[:, 0].astype(jnp.int32)
         return t - j, j
 
-    _, xs_rev = jax.lax.scan(step, t_star.astype(jnp.int32), I[::-1])
-    return jnp.swapaxes(xs_rev[::-1], 0, 1)  # (B, n)
+    _, xs = jax.lax.scan(step, t_star.astype(jnp.int32), I, reverse=True)
+    return jnp.swapaxes(xs, 0, 1)  # (B, n)
 
 
 @functools.partial(jax.jit, static_argnames=("T",))

@@ -107,7 +107,8 @@ def bucket_shape(B: int, n: int, T: int, W: int):
 
 
 # the keys of ``handle.phases``: host seconds of one dispatch and of its
-# phases, and the DP cells it launched (useful band cells, computed cells)
+# phases, and what its DP launched: useful band cells against computed cells,
+# and the class-scan rows with the real clients among them
 DISPATCH_PHASES = (
     "dispatch_s",
     "classify_s",
@@ -116,6 +117,8 @@ DISPATCH_PHASES = (
     "compile_s",
     "dp_band_cells",
     "dp_computed_cells",
+    "dp_rows",
+    "dp_live_rows",
 )
 
 
@@ -548,6 +551,8 @@ class SweepEngine:
                 Bb = ((Bb + self._ndev - 1) // self._ndev) * self._ndev
             phases["dp_band_cells"] += _band_cells(b0)
             phases["dp_computed_cells"] += Bb * nb * (Tb + 1) * Wb
+            phases["dp_rows"] += Bb * nb
+            phases["dp_live_rows"] += int(np.count_nonzero(b0.upper))
             padded = b0.pad_to(B=Bb, n=nb, W=Wb)
             costs = pack_problem(padded)  # (Bb, nb, Wb) float32, BIG-saturated
             t_star = jnp.asarray(padded.T, dtype=jnp.int32)
@@ -640,7 +645,9 @@ class SweepEngine:
         (``pack_s``), the executable calls (``launch_s``; a call that
         compiled counts under ``compile_s`` instead), and the DP's useful
         band cells (``dp_band_cells``) against the cells its executables
-        compute over their buckets (``dp_computed_cells``)."""
+        compute over their buckets (``dp_computed_cells``); the rows the
+        class scan runs, ``Bb * nb`` (``dp_rows``), and the real clients
+        among them with work to place (``dp_live_rows``)."""
         t0 = time.perf_counter()
         phases = dict.fromkeys(DISPATCH_PHASES, 0)
         with TraceAnnotation("repro.engine.dispatch"):

@@ -580,7 +580,9 @@ class SchedulerService:
         ``(T' + 1) * (U_i - L_i + 1)`` summed over real rows and clients,
         and ``dp_computed_cells`` what the DP executables compute over their
         buckets, ``Bb * nb * (Tb + 1) * Wb`` per flush; their ratio is the
-        share of the kernel's cells that is not padding.
+        share of the kernel's cells that is not padding. By rows:
+        ``dp_rows`` is the rows the class scan runs, ``Bb * nb`` per flush,
+        and ``dp_live_rows`` the real clients among them with work to place.
 
         The same stages are spans on the profiler's clock while a profile
         runs: ``repro.serve.submit`` (its ``pack``, and ``admit`` where

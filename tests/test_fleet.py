@@ -267,7 +267,7 @@ def test_ring_sharded_dp_bit_identical_forced_8_devices():
             "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
         )
         import sys
-        sys.path.insert(0, %r)
+        sys.path[:0] = %r
         import numpy as np
         import jax
         from repro.core import (SweepEngine, make_sweep_mesh, random_problem,
@@ -289,6 +289,32 @@ def test_ring_sharded_dp_bit_identical_forced_8_devices():
         assert np.array_equal(X_ring, X_ref), "ring-sharded != unsharded"
         assert np.array_equal(X_ring, X_un), "ring-sharded != uncached"
 
+        # argmin slabs narrowed to int8 (W 64) and int16 (W 256) on every
+        # ring device: schedules equal the int32 argmin path's
+        import jax.numpy as jnp
+        from _int32_dp import fused_int32
+        from repro.core import Problem
+        from repro.core.jax_dp import pack_problem
+        from repro.core.problem import ProblemBatch
+
+        for U in (63, 255):
+            wide = []
+            for b in range(3):
+                n = int(rng.integers(3, 12))
+                marg = rng.integers(1, 40, size=(n, U)).astype(float)
+                marg[0] = -1.0  # client 0's curve falls: it takes the whole band
+                tables = [np.concatenate([[500.0], 500.0 + np.cumsum(m)]) for m in marg]
+                T = int(rng.integers(U, n * U * 2 // 3 + 1))
+                wide.append(Problem(T=T, lower=np.zeros(n, np.int64),
+                                    upper=np.full(n, U, np.int64), cost_tables=tables))
+            X_wide = SweepEngine(ring_mesh=mesh).solve(wide)
+            b0 = ProblemBatch.from_problems(wide)
+            X32, _ = fused_int32(pack_problem(b0), jnp.asarray(b0.T, jnp.int32),
+                                 int(b0.T.max()), "blocked")
+            X32 = np.asarray(X32)
+            assert np.array_equal(X_wide, X32), f"ring at U={U} != int32 argmin path"
+            assert int(X32.max()) == U
+
         # mesh and ring_mesh are mutually exclusive
         try:
             SweepEngine(mesh=mesh, ring_mesh=mesh)
@@ -306,7 +332,7 @@ def test_ring_sharded_dp_bit_identical_forced_8_devices():
         assert abs(fsol.objective - float(flat.objectives[0])) <= 1e-6
         print("RING_OK")
         """
-        % os.path.join(REPO, "src")
+        % [os.path.join(REPO, "src"), os.path.dirname(os.path.abspath(__file__))]
     )
     proc = subprocess.run(
         [sys.executable, "-c", src], capture_output=True, text=True, timeout=420

@@ -35,6 +35,7 @@ from repro.core import (
     total_cost,
 )
 from repro.core.jax_dp import (
+    argmin_dtype,
     backtrack_batch_jax,
     dp_tables_batch_jax,
     pack_problem,
@@ -52,6 +53,8 @@ from repro.kernels import (
     resolve_backend,
     tpu_tuned_bt,
 )
+
+from _int32_dp import fused_int32
 
 
 def random_band_inputs(rng, B, Tp, W, frac_inf=0.3):
@@ -264,6 +267,40 @@ def test_fused_solver_matches_twodispatch_and_numpy_dp():
         k_at = float(np.asarray(k_last)[b, int(b0.T[b])])
         offset = sum(p.cost(i, int(lo)) for i, lo in enumerate(p.lower))
         assert k_at + offset == pytest.approx(total_cost(p, x_np), rel=1e-5, abs=1e-4)
+
+
+def _full_band_costs(rng, B, n, W):
+    """``(B, n, W)`` integer cost tables whose optimum takes the widest band
+    offset ``W - 1`` (client 0's curve falls all the way), with arbitrary
+    curves beside it, and workloads over two thirds of the capacity."""
+    marg = rng.integers(1, 40, size=(B, n, W)).astype(np.float32)
+    marg[:, 0, :] = -1.0
+    costs = np.cumsum(marg, axis=2) - marg[:, :, :1] + 500.0
+    T = rng.integers(W, n * (W - 1) * 2 // 3 + 2, size=B)
+    return costs.astype(np.float32), T.astype(np.int32)
+
+
+@pytest.mark.parametrize("backend", ["ref", "blocked"])
+@pytest.mark.parametrize("W", [2, 64, 127, 128, 129, 256, 257])
+def test_narrow_argmins_are_bit_identical_to_int32(W, backend):
+    rng = np.random.default_rng(W)
+    costs, t = _full_band_costs(rng, 2, 3, W)
+    T = int(t.max())
+    X, k_last = solve_fused_batch_jax(jnp.asarray(costs), jnp.asarray(t), T, backend=backend)
+    X32, k32 = fused_int32(jnp.asarray(costs), jnp.asarray(t), T, backend)
+    np.testing.assert_array_equal(np.asarray(X), np.asarray(X32))
+    np.testing.assert_array_equal(np.asarray(k_last), np.asarray(k32))
+    assert np.asarray(X).dtype == np.int32 and int(np.asarray(X32).max()) == W - 1
+    _, I = dp_tables_batch_jax(jnp.asarray(costs), T, backend=backend)
+    assert I.dtype == argmin_dtype(W) == (jnp.int8 if W <= 128 else jnp.int16)
+
+
+def test_argmin_dtype_holds_every_band_offset():
+    for W in (1, 2, 128, 129, 32768, 32769, 1 << 20):
+        dt = np.dtype(argmin_dtype(W))
+        assert np.iinfo(dt).max >= W - 1
+        assert dt.itemsize == 1 or np.iinfo(np.dtype(f"int{4 * dt.itemsize}")).max < W - 1
+    assert np.dtype(argmin_dtype(1 << 16)) == np.int32
 
 
 def test_batched_solver_blocked_bit_identical_to_ref_end_to_end():

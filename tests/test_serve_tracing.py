@@ -23,7 +23,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import SweepEngine, random_problem
+from repro.core import Problem, SweepEngine, random_problem
 from repro.core.sweep import DISPATCH_PHASES
 from repro.serve import SchedulerService
 
@@ -99,6 +99,27 @@ def test_counts_and_cells_over_two_buckets():
     assert st["dp_band_cells"] == sum(b for b, _ in want)
     assert st["dp_computed_cells"] == sum(c for _, c in want)
     assert 0 < st["dp_band_cells"] < st["dp_computed_cells"]
+
+
+def test_dp_rows_and_live_rows_per_flush():
+    """Rows the class scan runs (``Bb * nb``) and the clients among them
+    with work, summed over flushes of several buckets."""
+    requests = _requests(35)
+    rng = np.random.default_rng(36)
+    upper = np.array([200, 50, 60, 70, 80])  # band 201: the W 256 bucket
+    tables = [np.cumsum(rng.integers(0, 30, size=u + 1)).astype(float) for u in upper]
+    wide = Problem(T=300, lower=np.zeros(5, np.int64), upper=upper, cost_tables=tables)
+    requests.append([wide])
+    svc = SchedulerService(engine=SweepEngine(), max_batch=4, max_delay_s=0.001)
+    _serve_one_at_a_time(svc, requests)
+    svc.close(timeout=60)
+    st = svc.stats()
+    rows = live = 0
+    for probs in requests:
+        rows += _pow2(len(probs)) * _pow2(max(p.n for p in probs))
+        live += sum(int(np.count_nonzero(p.upper - p.lower)) for p in probs)
+    assert (st["dp_rows"], st["dp_live_rows"]) == (rows, live)
+    assert 0 < st["dp_live_rows"] < st["dp_rows"]
 
 
 def test_classify_is_timed_only_on_the_regime_split_path():

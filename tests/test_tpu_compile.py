@@ -25,6 +25,8 @@ from repro.kernels.minplus import minplus_pallas_batch, tpu_tuned_bt
 SILO_B, SILO_N, SILO_T, SILO_W = 8, 32, 16384, 1024
 # the cross-device monotone cell
 MONO_B, MONO_N, MONO_W = 4, 4096, 64
+# the cross-device uplink cell: the DP over 2 130-3 550 phones, T' up to 57k
+UPLINK_B, UPLINK_N, UPLINK_T, UPLINK_W = 8, 4096, 65536, 64
 FLEET_N = 16384
 V5E_HBM_BYTES = 16 * 10**9
 
@@ -101,6 +103,22 @@ def test_fused_engine_program_compiles_with_pallas_tpu(one_chip):
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert engine.cache_stats()["compiles"] == 1
+
+
+def test_uplink_dp_program_keeps_an_int8_argmin_matrix_within_hbm(one_chip):
+    # the largest executable the uplink cell warms: B = 8 at its top bucket
+    engine = SweepEngine(backend="pallas_tpu")
+    run = engine._build(("dp", UPLINK_B, UPLINK_N, UPLINK_T, UPLINK_W))
+    compiled = run.lower(
+        _spec((UPLINK_B, UPLINK_N, UPLINK_W), jnp.float32, one_chip),
+        _spec((UPLINK_B,), jnp.int32, one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert f"s8[{UPLINK_N},{UPLINK_B},{UPLINK_T + 1}]" in text
+    assert f"s32[{UPLINK_N},{UPLINK_B},{UPLINK_T + 1}]" not in text
+    slab = UPLINK_N * UPLINK_B * (UPLINK_T + 1)  # bytes at int8
+    mem = compiled.memory_analysis()
+    assert slab <= mem.temp_size_in_bytes < V5E_HBM_BYTES
 
 
 @pytest.mark.parametrize("strategy", ["mesh", "ring_mesh"])

@@ -35,6 +35,7 @@ from .problem import (
 
 __all__ = [
     "argmin_dtype",
+    "band_bounds",
     "solve_schedule_dp_jax",
     "solve_schedule_dp_batch",
     "solve_fused_batch_jax",
@@ -120,21 +121,34 @@ def argmin_dtype(W: int):
     return jnp.int32
 
 
+def band_bounds(costs: jnp.ndarray) -> jnp.ndarray:
+    """``(B, n)`` int32 band bound of each packed cost row ``costs (B, n,
+    W)``: one past its last entry below BIG, 0 for a row of BIG alone. The
+    last finite index, not the count of finite entries, since a table may
+    hold an interior BIG."""
+    j = jnp.arange(1, costs.shape[2] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(costs < BIG, j, 0), axis=2)
+
+
 def _dp_scan_from(k0: jnp.ndarray, costs: jnp.ndarray, backend: str = "ref"):
     """Continues the class scan from an arbitrary DP row ``k0 (B, T+1)`` over
     the classes in ``costs (B, n, W)``. Factored out of
     :func:`_dp_tables_batch` so the ring-sharded solver (below) can run each
     device's local classes through the IDENTICAL op sequence — bit-identity
     of the sharded path reduces to handing the row around the ring. Each
-    argmin row is narrowed to :func:`argmin_dtype` as it is stacked."""
+    row's band bound (:func:`band_bounds`) is computed once, before the
+    scan, and rides beside its cost row. Each argmin row is narrowed to
+    :func:`argmin_dtype` as it is stacked."""
     narrow = argmin_dtype(costs.shape[2])
 
-    def step(krow, cost_i):
-        kout, iout = minplus_step_batch(krow, cost_i, backend=backend)
+    def step(krow, xs):
+        cost_i, band_i = xs
+        kout, iout = minplus_step_batch(krow, cost_i, backend=backend, band=band_i)
         return kout, iout.astype(narrow)
 
     # scan over the class axis: xs must lead with n
-    return jax.lax.scan(step, k0, jnp.swapaxes(costs, 0, 1))
+    xs = (jnp.swapaxes(costs, 0, 1), jnp.swapaxes(band_bounds(costs), 0, 1))
+    return jax.lax.scan(step, k0, xs)
 
 
 def _dp_tables_batch(costs: jnp.ndarray, T: int, backend: str = "ref"):

@@ -64,6 +64,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import AxisType, NamedSharding, PartitionSpec
 
+from ..kernels.minplus import band_steps
 from ..kernels.ops import resolve_backend
 from ._deprecation import warn_deprecated
 from .jax_dp import _solve_fused_batch, pack_problem, solve_fused_batch_ring
@@ -107,8 +108,9 @@ def bucket_shape(B: int, n: int, T: int, W: int):
 
 
 # the keys of ``handle.phases``: host seconds of one dispatch and of its
-# phases, and what its DP launched: useful band cells against computed cells,
-# and the class-scan rows with the real clients among them
+# phases, and what its DP launched: useful band cells against computed cells
+# and the cells its band loops run, and the class-scan rows with the real
+# clients among them
 DISPATCH_PHASES = (
     "dispatch_s",
     "classify_s",
@@ -117,6 +119,7 @@ DISPATCH_PHASES = (
     "compile_s",
     "dp_band_cells",
     "dp_computed_cells",
+    "dp_run_cells",
     "dp_rows",
     "dp_live_rows",
 )
@@ -148,6 +151,20 @@ def _band_cells(b0: ProblemBatch) -> int:
     among them, needs no min-plus). Padding to the bucket adds none."""
     widths = np.where(b0.upper > 0, b0.upper + 1, 0).sum(axis=1)
     return int(((b0.T + 1) * widths).sum())
+
+
+def _run_cells(b0: ProblemBatch, Bb: int, nb: int, Tb: int, Wb: int, backend: str) -> int:
+    """Cells the min-plus row updates of a ``(Bb, nb, Tb, Wb)`` bucket run:
+    ``(Tb + 1)`` times the band steps of each of the ``Bb * nb`` rows. The
+    Pallas TPU kernel runs :func:`~repro.kernels.minplus.band_steps` of each
+    row's band ``U'_i + 1`` (the padding rows' is 1), the other backends the
+    whole bucket. Counted from the limits, so on the Pallas kernel it is the
+    loop's trip count wherever a client's last table entry is finite."""
+    if backend not in ("pallas", "pallas_tpu"):
+        return Bb * nb * (Tb + 1) * Wb
+    band = np.ones((Bb, nb), dtype=np.int64)
+    band[: b0.B, : b0.n] = b0.upper + 1
+    return (Tb + 1) * int(band_steps(band, Wb).sum())
 
 
 def _bucket_axes(b0: ProblemBatch):
@@ -551,6 +568,7 @@ class SweepEngine:
                 Bb = ((Bb + self._ndev - 1) // self._ndev) * self._ndev
             phases["dp_band_cells"] += _band_cells(b0)
             phases["dp_computed_cells"] += Bb * nb * (Tb + 1) * Wb
+            phases["dp_run_cells"] += _run_cells(b0, Bb, nb, Tb, Wb, self.backend)
             phases["dp_rows"] += Bb * nb
             phases["dp_live_rows"] += int(np.count_nonzero(b0.upper))
             padded = b0.pad_to(B=Bb, n=nb, W=Wb)
@@ -645,8 +663,10 @@ class SweepEngine:
         (``pack_s``), the executable calls (``launch_s``; a call that
         compiled counts under ``compile_s`` instead), and the DP's useful
         band cells (``dp_band_cells``) against the cells its executables
-        compute over their buckets (``dp_computed_cells``); the rows the
-        class scan runs, ``Bb * nb`` (``dp_rows``), and the real clients
+        compute over their buckets (``dp_computed_cells``) and the cells
+        their band loops run (``dp_run_cells``: the Pallas kernel ends each
+        row's loop at its band, other backends run the whole bucket); the
+        rows the class scan runs, ``Bb * nb`` (``dp_rows``), and the real clients
         among them with work to place (``dp_live_rows``)."""
         t0 = time.perf_counter()
         phases = dict.fromkeys(DISPATCH_PHASES, 0)

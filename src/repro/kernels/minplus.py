@@ -17,9 +17,14 @@ and ``Solver`` and the round driver solve one instance, so packing eight
 instances into the sublanes would leave them as empty as before.
 
 The inner loop walks the band ``j = 0 .. W-1`` in ascending order,
-``BAND_UNROLL`` steps per iteration. For ``j = 128q + s`` each sublane's
-window ``halo[r, Wp + base - j : Wp + base - j + BT]`` is built from one
-ALIGNED ``(8, BT + 128)`` load that starts ``128 (q + 1)`` before the tile,
+``BAND_UNROLL`` steps per iteration, and ends at the batch element's band
+bound ``band[b]`` (one past its last finite cost entry), rounded up to
+whole groups of ``BAND_UNROLL``: a step whose cost entry is BIG cannot win
+(its candidate saturates to BIG and the argmin test is strict), so the
+steps past the bound change neither value nor argmin (DESIGN.md §3).
+For ``j = 128q + s`` each sublane's window
+``halo[r, Wp + base - j : Wp + base - j + BT]`` is built from one ALIGNED
+``(8, BT + 128)`` load that starts ``128 (q + 1)`` before the tile,
 lane-rotated by ``s`` (``pltpu.roll``) and sliced at the static, aligned
 offset 128 — Mosaic refuses unaligned dynamic lane slices. The cost entry
 ``C_i[j]`` is a scalar read from SMEM, and the running min / argmin carries
@@ -29,6 +34,7 @@ same strict first-minimum rule, as :mod:`repro.kernels.ref`.
 Layout (per batch element; the batch axis is a squeezed grid block, so the
 last two block dimensions always equal the array's or are lane multiples —
 the (8, 128) rule):
+  band      : (B,)            SMEM, scalar prefetch: band bound per element
   cost      : (1, W)          SMEM, class cost table padded with BIG
   halo      : (8, Wp + S)     VMEM, ``halo[r] = kprev_pad[r*S : r*S + Wp + S]``
               where ``kprev_pad`` is the row behind ``Wp`` BIG entries and
@@ -49,12 +55,20 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .ref import BIG
 
-__all__ = ["minplus_pallas", "minplus_pallas_batch", "tpu_tuned_bt", "DEFAULT_BT"]
+__all__ = [
+    "minplus_pallas",
+    "minplus_pallas_batch",
+    "tpu_tuned_bt",
+    "band_steps",
+    "DEFAULT_BT",
+    "BAND_UNROLL",
+]
 
 LANES = 128  # f32 lane width of a vreg: the alignment unit of every slice
 SUBLANES = 8  # f32 sublanes of a vreg: the row is folded into this many segments
@@ -110,10 +124,21 @@ def tpu_tuned_bt(Tp: int, W: int) -> int:
     return bt
 
 
-def _minplus_batch_kernel(cost_ref, halo_ref, kout_ref, iout_ref, *, BT: int, W: int, Wp: int):
+def band_steps(band, W: int):
+    """Band steps the kernel runs for a row whose band bound is ``band``
+    (one past its last finite cost entry) in a table of width ``W``:
+    ``min(W, band rounded up to BAND_UNROLL)``. Works on numpy arrays."""
+    return np.minimum(W, -(-np.asarray(band) // BAND_UNROLL) * BAND_UNROLL)
+
+
+def _minplus_batch_kernel(
+    band_ref, cost_ref, halo_ref, kout_ref, iout_ref, *, BT: int, W: int, Wp: int
+):
     """Grid is ``(b, ot)``; each program owns one ``(8, BT)`` output tile of
     one batch element: lanes ``[base, base + BT)`` of all eight segments, with
-    that element's whole halo block resident."""
+    that element's whole halo block resident. The band loop runs
+    :func:`band_steps` of ``band_ref[b]``, in ascending ``j``."""
+    band = band_ref[pl.program_id(0)]
     base = pl.program_id(1) * BT  # offset of this tile's first element in its segment
 
     def step(j, carry):
@@ -137,23 +162,30 @@ def _minplus_batch_kernel(cost_ref, halo_ref, kout_ref, iout_ref, *, BT: int, W:
             carry = step(i * BAND_UNROLL + u, carry)
         return carry
 
+    whole = W - W % BAND_UNROLL  # band steps in whole unrolled groups
+    groups = jnp.minimum(whole // BAND_UNROLL, (band + BAND_UNROLL - 1) // BAND_UNROLL)
     carry = (jnp.full((SUBLANES, BT), BIG, jnp.float32), jnp.zeros((SUBLANES, BT), jnp.int32))
-    carry = jax.lax.fori_loop(0, W // BAND_UNROLL, unrolled, carry)
-    # the last W % BAND_UNROLL band steps, in order
-    best, best_idx = jax.lax.fori_loop(W - W % BAND_UNROLL, W, step, carry)
+    best, best_idx = jax.lax.fori_loop(0, groups, unrolled, carry)
+    if whole < W:
+        # the last W % BAND_UNROLL band steps, in order, where the bound
+        # reaches past the whole groups
+        end = jnp.where(band > whole, W, whole)
+        best, best_idx = jax.lax.fori_loop(whole, end, step, (best, best_idx))
     kout_ref[...] = best
     iout_ref[...] = best_idx
 
 
-def _minplus_pallas_call(kprev, cost, BT: int, interpret: bool) -> tuple:
+def _minplus_pallas_call(kprev, cost, band, BT: int, interpret: bool) -> tuple:
     """Unjitted body shared by both entry points (jit-of-jit would trace a
-    second wrapper per shape for zero caching benefit)."""
+    second wrapper per shape for zero caching benefit). ``band`` is ``(B,)``
+    or ``None`` for the whole width."""
     if BT % LANES:
         raise ValueError(f"BT={BT} must be a multiple of {LANES}")
     kprev = kprev.astype(jnp.float32)
     cost = cost.astype(jnp.float32)
     B, Tp = kprev.shape
     W = cost.shape[1]
+    band = jnp.full((B,), W, jnp.int32) if band is None else band.astype(jnp.int32)
     Wp = _round_up(W, LANES)
     S = _segment(Tp, BT)
     kprev_pad = jnp.concatenate(
@@ -167,23 +199,27 @@ def _minplus_pallas_call(kprev, cost, BT: int, interpret: bool) -> tuple:
     # halo[b, r] = kprev_pad[b, r*S : r*S + Wp + S]: segment r behind the Wp
     # entries before it, so sublane r's band reads use the unfolded row's offsets
     halo = jnp.stack([kprev_pad[:, r * S : r * S + Wp + S] for r in range(SUBLANES)], axis=1)
-    tile = pl.BlockSpec((None, SUBLANES, BT), lambda b, ot: (b, 0, ot))
-    kout, iout = pl.pallas_call(
-        functools.partial(_minplus_batch_kernel, BT=BT, W=W, Wp=Wp),
+    tile = pl.BlockSpec((None, SUBLANES, BT), lambda b, ot, band: (b, 0, ot))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # band: one bound per batch element, in SMEM
         grid=(B, S // BT),
         in_specs=[
-            pl.BlockSpec((None, 1, W), lambda b, ot: (b, 0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, 1, W), lambda b, ot, band: (b, 0, 0), memory_space=pltpu.SMEM),
             # the halo's block index ignores ot: it stays resident while ot walks it
-            pl.BlockSpec((None, SUBLANES, Wp + S), lambda b, ot: (b, 0, 0)),
+            pl.BlockSpec((None, SUBLANES, Wp + S), lambda b, ot, band: (b, 0, 0)),
         ],
         out_specs=[tile, tile],
+    )
+    kout, iout = pl.pallas_call(
+        functools.partial(_minplus_batch_kernel, BT=BT, W=W, Wp=Wp),
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, SUBLANES, S), jnp.float32),
             jax.ShapeDtypeStruct((B, SUBLANES, S), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(cost[:, None, :], halo)
+    )(band, cost[:, None, :], halo)
     return kout.reshape(B, SUBLANES * S)[:, :Tp], iout.reshape(B, SUBLANES * S)[:, :Tp]
 
 
@@ -191,6 +227,7 @@ def _minplus_pallas_call(kprev, cost, BT: int, interpret: bool) -> tuple:
 def minplus_pallas_batch(
     kprev: jnp.ndarray,
     cost: jnp.ndarray,
+    band=None,
     *,
     BT: int = DEFAULT_BT,
     interpret: bool = False,
@@ -199,25 +236,33 @@ def minplus_pallas_batch(
     :func:`repro.kernels.ref.minplus_step_ref_batch`: ``kprev (B, T+1)``,
     ``cost (B, W)`` -> ``(B, T+1)`` values + int32 argmins.
 
+    ``band (B,)``, where given, is each element's band bound: one past the
+    last ``j`` with ``cost[b, j] < BIG``, or more. The band loop stops
+    there (:func:`band_steps`); the answer is the same as without it.
+    ``None`` walks the whole width ``W``.
+
     One ``(b, ot)`` grid; batch elements are independent, so the grid is
     embarrassingly parallel across both axes. ``BT`` is a multiple of 128.
     The kernel compiles for a TPU; ``interpret=True`` runs it through the
     Pallas interpreter instead, for tests on the CPU.
     """
-    return _minplus_pallas_call(kprev, cost, BT, interpret)
+    return _minplus_pallas_call(kprev, cost, band, BT, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("BT", "interpret"))
 def minplus_pallas(
     kprev: jnp.ndarray,
     cost: jnp.ndarray,
+    band=None,
     *,
     BT: int = DEFAULT_BT,
     interpret: bool = False,
 ) -> tuple:
     """One DP row update via Pallas: the ``B = 1`` slice of the batched
-    kernel. Same contract as :func:`repro.kernels.ref.minplus_step_ref`."""
+    kernel. Same contract as :func:`repro.kernels.ref.minplus_step_ref`;
+    ``band`` is a scalar bound or ``None``."""
+    band = None if band is None else jnp.reshape(band, (1,))
     kout, iout = _minplus_pallas_call(
-        jnp.asarray(kprev)[None], jnp.asarray(cost)[None], BT, interpret
+        jnp.asarray(kprev)[None], jnp.asarray(cost)[None], band, BT, interpret
     )
     return kout[0], iout[0]

@@ -22,6 +22,13 @@ compiled ``pallas_tpu``, and elsewhere it raises. Resolution happens at
 Python/trace time (``jax.default_backend()`` is not a traced value), so
 "auto" and its resolved backend share jit caches when callers resolve
 before specializing — see :func:`resolve_backend`.
+
+Both entry points take an optional ``band``: each row's band bound, one
+past its last finite cost entry (``None`` means the whole width ``W``). The
+Pallas TPU kernel ends its band loop there
+(:func:`~repro.kernels.minplus.band_steps`); the other backends walk the
+whole width. Every backend gives the same answer either way, since a step
+whose cost is BIG never wins.
 """
 
 from __future__ import annotations
@@ -73,8 +80,9 @@ def resolve_backend(backend: str | None = "auto") -> str:
     return backend
 
 
-def minplus_step(kprev: jnp.ndarray, cost: jnp.ndarray, backend: str = "auto"):
-    """One DP row update: ``kprev (T+1,)``, ``cost (W,)``."""
+def minplus_step(kprev: jnp.ndarray, cost: jnp.ndarray, backend: str = "auto", band=None):
+    """One DP row update: ``kprev (T+1,)``, ``cost (W,)``, ``band`` a scalar
+    bound or ``None``."""
     backend = resolve_backend(backend)
     if backend == "ref":
         return minplus_step_ref(kprev, cost)
@@ -82,14 +90,17 @@ def minplus_step(kprev: jnp.ndarray, cost: jnp.ndarray, backend: str = "auto"):
         return minplus_blocked(kprev, cost)
     if backend in ("pallas", "pallas_tpu"):
         bt = tpu_tuned_bt(kprev.shape[0], cost.shape[0])
-        return minplus_pallas(kprev, cost, BT=bt, interpret=backend == "pallas")
+        return minplus_pallas(kprev, cost, band, BT=bt, interpret=backend == "pallas")
     if backend == "pallas_gpu":
         return minplus_pallas_gpu(kprev, cost, interpret=False)
     raise ValueError(f"unknown backend {backend!r}")
 
 
-def minplus_step_batch(kprev: jnp.ndarray, cost: jnp.ndarray, backend: str = "auto"):
-    """Batched row update: ``kprev (B, T+1)``, ``cost (B, W)``."""
+def minplus_step_batch(
+    kprev: jnp.ndarray, cost: jnp.ndarray, backend: str = "auto", band=None
+):
+    """Batched row update: ``kprev (B, T+1)``, ``cost (B, W)``, ``band (B,)``
+    or ``None``."""
     backend = resolve_backend(backend)
     if backend == "ref":
         return minplus_step_ref_batch(kprev, cost)
@@ -97,7 +108,7 @@ def minplus_step_batch(kprev: jnp.ndarray, cost: jnp.ndarray, backend: str = "au
         return minplus_blocked_batch(kprev, cost)
     if backend in ("pallas", "pallas_tpu"):
         bt = tpu_tuned_bt(kprev.shape[1], cost.shape[1])
-        return minplus_pallas_batch(kprev, cost, BT=bt, interpret=backend == "pallas")
+        return minplus_pallas_batch(kprev, cost, band, BT=bt, interpret=backend == "pallas")
     if backend == "pallas_gpu":
         return minplus_pallas_gpu_batch(kprev, cost, interpret=False)
     raise ValueError(f"unknown backend {backend!r}")

@@ -580,7 +580,10 @@ class SchedulerService:
         ``(T' + 1) * (U_i - L_i + 1)`` summed over real rows and clients,
         and ``dp_computed_cells`` what the DP executables compute over their
         buckets, ``Bb * nb * (Tb + 1) * Wb`` per flush; their ratio is the
-        share of the kernel's cells that is not padding. By rows:
+        share of the kernel's cells that is not padding. ``dp_run_cells``
+        is the cells the kernel's band loops run: on the Pallas TPU kernel
+        each row's loop ends at its band ``U' + 1`` rounded up to the
+        kernel's unroll, elsewhere it is ``dp_computed_cells``. By rows:
         ``dp_rows`` is the rows the class scan runs, ``Bb * nb`` per flush,
         and ``dp_live_rows`` the real clients among them with work to place.
 

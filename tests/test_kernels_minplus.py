@@ -16,6 +16,7 @@ from repro.kernels import (
     minplus_step_ref,
     minplus_step_ref_batch,
 )
+from repro.kernels.minplus import band_steps
 
 
 def numpy_minplus(kprev, cost):
@@ -127,3 +128,75 @@ def test_dp_via_pallas_backend_end_to_end():
         x_pal = solve_schedule_dp_jax(p, backend="pallas")
         x_np = solve_schedule_dp(p)
         assert total_cost(p, x_pal) == pytest.approx(total_cost(p, x_np), rel=1e-5)
+
+
+def _banded_rows(rng, bounds, W):
+    """Cost rows of width ``W`` whose last finite entry is at ``bound - 1``,
+    BIG after it: rebased costs, some negative; the first row with room
+    holds an interior BIG too."""
+    cost = rng.uniform(-8, 10, size=(len(bounds), W)).astype(np.float32)
+    for b, bound in enumerate(bounds):
+        cost[b, bound:] = float(BIG)
+    interior = [b for b, bound in enumerate(bounds) if bound > 2]
+    if interior:
+        b = interior[0]
+        cost[b, bounds[b] // 2] = float(BIG)
+    return cost
+
+
+@pytest.mark.parametrize("W,bounds", [
+    (64, (1,)),
+    (64, (31,)),
+    (64, (1, 33, 64)),
+    (64, (32, 31, 1)),
+    (100, (33,)),
+    (100, (1, 32, 100)),
+    (100, (97, 99, 31)),  # past the last whole group of 32: the remainder runs
+    (1024, (32,)),
+    (1024, (1, 33, 1024)),
+])
+def test_pallas_band_bound_matches_ref(W, bounds):
+    """With each row's own band bound the kernel stays bit-identical to the
+    oracle, and a full-width row is unchanged by the bound."""
+    B, Tp = len(bounds), 300
+    rng = np.random.default_rng(W * 100 + sum(bounds))
+    kprev = np.stack([random_row(rng, Tp) for _ in range(B)])
+    kprev -= np.float32(40.0) * (kprev < float(BIG))  # negative DP values too
+    kprev[:, rng.choice(Tp, size=Tp // 10, replace=False)] = float(BIG)  # all-BIG columns
+    kprev[:, 0] = 0.0
+    cost = _banded_rows(rng, bounds, W)
+    band = np.asarray(bounds, np.int32)
+    ref_v, ref_i = minplus_step_ref_batch(kprev, cost)
+    pal_v, pal_i = minplus_pallas_batch(kprev, cost, band, BT=128, interpret=True)
+    np.testing.assert_array_equal(np.asarray(pal_v), np.asarray(ref_v))
+    np.testing.assert_array_equal(np.asarray(pal_i), np.asarray(ref_i))
+    full = np.full(B, W, np.int32)
+    full_v, full_i = minplus_pallas_batch(kprev, cost, full, BT=128, interpret=True)
+    none_v, none_i = minplus_pallas_batch(kprev, cost, BT=128, interpret=True)
+    np.testing.assert_array_equal(np.asarray(full_v), np.asarray(none_v))
+    np.testing.assert_array_equal(np.asarray(full_i), np.asarray(none_i))
+    np.testing.assert_array_equal(np.asarray(none_v), np.asarray(ref_v))
+    np.testing.assert_array_equal(np.asarray(none_i), np.asarray(ref_i))
+    # the single-instance entry point takes a scalar bound
+    one_v, one_i = minplus_pallas(kprev[0], cost[0], band[0], BT=128, interpret=True)
+    np.testing.assert_array_equal(np.asarray(one_v), np.asarray(ref_v)[0])
+    np.testing.assert_array_equal(np.asarray(one_i), np.asarray(ref_i)[0])
+
+
+@pytest.mark.parametrize("W,bounds", [(64, (1, 31, 33)), (100, (32, 97, 100))])
+def test_pallas_band_bound_ends_the_band_loop(W, bounds):
+    """Where the costs run on past the bound, the kernel runs exactly
+    ``band_steps`` of it: its answer is the oracle's on the table cut there."""
+    B, Tp = len(bounds), 300
+    rng = np.random.default_rng(W + sum(bounds))
+    kprev = np.stack([random_row(rng, Tp) for _ in range(B)])
+    cost = rng.uniform(0, 10, size=(B, W)).astype(np.float32)  # finite to W
+    steps = band_steps(np.asarray(bounds), W)
+    cut = np.where(np.arange(W)[None, :] < steps[:, None], cost, np.float32(BIG))
+    ref_v, ref_i = minplus_step_ref_batch(kprev, cut)
+    pal_v, pal_i = minplus_pallas_batch(
+        kprev, cost, np.asarray(bounds, np.int32), BT=128, interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(pal_v), np.asarray(ref_v))
+    np.testing.assert_array_equal(np.asarray(pal_i), np.asarray(ref_i))
+    assert list(steps) == [min(W, -(-b // 32) * 32) for b in bounds]

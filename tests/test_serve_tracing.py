@@ -282,3 +282,49 @@ def test_counters_hold_under_concurrent_submitters():
     assert st["taken_requests"] == st["requests"] == st["completed_requests"]
     assert st["landed_flushes"] == st["flushes"]
     assert st["dp_band_cells"] - before["dp_band_cells"] == sum(_cells([p])[0] for p in probs)
+
+
+def _table(rng, u: int):
+    return np.cumsum(rng.integers(1, 30, size=u + 1)).astype(float)
+
+
+def test_dp_run_cells_count_the_band_loops():
+    """``dp_run_cells`` is zero on a new service; on the blocked backend it
+    is every computed cell; on the Pallas kernel it counts each row's band
+    steps, ``min(Wb, ceil((U' + 1) / 32) * 32)``, with padding rows at
+    ``U' = 0``, times ``Tb + 1``, for a known run of two flushes."""
+    rng = np.random.default_rng(37)
+    # flush 1: U' 38, 5, 0 (lower limits 2, 0, 0) in the W 64 bucket, n 4,
+    # T' 18 -> Tb 32: 33 * (64 + 32 + 32 + 32 for the padding row)
+    one = Problem(
+        T=20,
+        lower=np.array([2, 0, 0]),
+        upper=np.array([40, 5, 0]),
+        cost_tables=[_table(rng, u) for u in (40, 5, 0)],
+    )
+    # flush 2: two instances of U' (99, 31) and (32, 10) in the W 128
+    # bucket, Tb 64: 65 * (128 + 32 + 64 + 32)
+    two = [
+        Problem(
+            T=40,
+            lower=np.zeros(2, np.int64),
+            upper=np.array(u),
+            cost_tables=[_table(rng, v) for v in u],
+        )
+        for u in ((99, 31), (32, 10))
+    ]
+    want = 33 * (64 + 32 + 32 + 32) + 65 * (128 + 32 + 64 + 32)
+    requests = [[one], two]
+    for backend in ("blocked", "pallas"):
+        svc = SchedulerService(engine=SweepEngine(backend=backend), max_batch=4, max_delay_s=0.001)
+        assert svc.stats()["dp_run_cells"] == 0
+        _serve_one_at_a_time(svc, requests)
+        svc.close(timeout=60)
+        st = svc.stats()
+        assert st["flushes"] == 2
+        assert st["dp_computed_cells"] == 1 * 4 * 33 * 64 + 2 * 2 * 65 * 128
+        if backend == "blocked":
+            assert st["dp_run_cells"] == st["dp_computed_cells"]
+        else:
+            assert st["dp_run_cells"] == want
+            assert st["dp_band_cells"] < st["dp_run_cells"] < st["dp_computed_cells"]

@@ -78,6 +78,24 @@ def test_minplus_kernel_compiles_at_cross_silo_shape(one_chip, B):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize(
+    "B,Tp,W",
+    [(1, UPLINK_T + 1, UPLINK_W), (SILO_B, SILO_T + 1, SILO_W)],
+    ids=["uplink", "cross-silo"],
+)
+def test_band_bounded_kernel_compiles(one_chip, B, Tp, W):
+    # the band bound is a scalar-prefetch operand and the band loop's trip
+    # count depends on it
+    compiled = minplus_pallas_batch.lower(
+        _spec((B, Tp), jnp.float32, one_chip),
+        _spec((B, W), jnp.float32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+        BT=tpu_tuned_bt(Tp, W),
+        interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_minplus_kernel_compiles_at_longest_resident_row(one_chip):
     # the longest row tpu_tuned_bt admits, found against its own VMEM model
     lo, hi = SILO_T, 1 << 26

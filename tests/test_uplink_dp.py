@@ -126,3 +126,47 @@ def test_served_uplink_schedules_cost_the_exact_optimum(seed, n):
         assert abs(objective - served) <= gamma * served
     # an upload makes taking part cost: some eligible phones are left out
     assert all((x == 0).any() for x, _ in answers)
+
+
+def test_fused_dp_on_the_pallas_kernel_matches_ref_and_the_numpy_dp():
+    """The engine's fused DP on the Pallas kernel (interpret mode), whose band
+    loops end at each row's bound, against the same DP on the ref backend and
+    the serial numpy DP: ragged limits, lower limits, phantom rows from the
+    pow2 buckets, W 64. Integer mJ tables below 2**24 in sum, so float32 is
+    exact and schedules and final rows must agree bit for bit."""
+    from repro.core.jax_dp import pack_problem, solve_fused_batch_jax
+    from repro.core.mc2mkp import _classes_from_problem, mc2mkp_matrices, solve_schedule_dp
+    from repro.core.problem import remove_lower_limits
+    from repro.kernels import BIG
+
+    rng = np.random.default_rng(17)
+    upper, tables = uplink_population(rng, 12)
+    tables = [np.rint(t / 100.0) for t in tables]
+    problems = []
+    for n in (12, 9, 5):  # ragged n: phantom rows to the n bucket of 16
+        idx = np.sort(rng.choice(12, size=n, replace=False))
+        u = upper[idx]
+        lower = np.minimum(rng.integers(0, 3, size=n), u)
+        problems.append(
+            Problem(
+                T=int(lower.sum() + rng.uniform(0.3, 0.7) * (u - lower).sum()),
+                lower=lower,
+                upper=u,
+                cost_tables=[tables[i] for i in idx],
+            )
+        )
+    batch = remove_lower_limits(ProblemBatch.from_problems(problems)).pad_to(B=4, n=16, W=64)
+    costs = pack_problem(batch)
+    t_star = np.asarray(batch.T, np.int32)
+    Tb = 1 << int(batch.T.max()).bit_length()
+    X_pal, K_pal = map(np.asarray, solve_fused_batch_jax(costs, t_star, Tb, backend="pallas"))
+    X_ref, K_ref = map(np.asarray, solve_fused_batch_jax(costs, t_star, Tb, backend="ref"))
+    np.testing.assert_array_equal(X_pal, X_ref)
+    np.testing.assert_array_equal(K_pal, K_ref)
+    for b, p in enumerate(problems):
+        p0 = remove_lower_limits(p)
+        np.testing.assert_array_equal(X_pal[b, : p.n] + p.lower, solve_schedule_dp(p))
+        K, _ = mc2mkp_matrices(_classes_from_problem(p0), int(p0.T))
+        want = np.where(np.isfinite(K[-1]), K[-1], float(BIG)).astype(np.float32)
+        np.testing.assert_array_equal(K_pal[b, : p0.T + 1], want)
+    assert np.all(X_pal[len(problems) :] == 0) and np.all(X_pal[:, 12:] == 0)

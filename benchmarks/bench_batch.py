@@ -3,10 +3,11 @@
 A what-if sweep — ``B`` candidate workloads over one fleet — is solved two
 ways:
 
-  * ``loop``:  a Python loop of ``B`` single-instance jitted solves
-    (:func:`solve_schedule_dp_jax`); every distinct ``T`` compiles its own
-    program, and every instance pays packing + dispatch + device_get.
-  * ``batch``: ONE :func:`solve_schedule_dp_batch` call — the instances are
+  * ``loop``:  a Python loop of ``B`` single-instance solves, each through
+    :func:`solve_fused_batch_jax` on its own pack; every distinct ``T``
+    compiles its own program, and every instance pays packing + dispatch +
+    device_get.
+  * ``batch``: ONE :func:`solve_fused_batch_jax` call — the instances are
     stacked ``(B, n, W)`` and the whole sweep is a single compiled program.
 
 Reports cold (fresh jit caches, the first-sweep experience) and warm
@@ -21,10 +22,13 @@ import argparse
 import json
 import time
 
+import jax.numpy as jnp
 import numpy as np
 
-from repro.core import Problem, random_problem
-from repro.core.jax_dp import solve_schedule_dp_batch, solve_schedule_dp_jax
+from repro.core import Problem, ProblemBatch, random_problem
+from repro.core.jax_dp import pack_problem, solve_fused_batch_jax
+from repro.core.problem import remove_lower_limits, restore_lower_limits
+from repro.kernels import resolve_backend
 
 
 def make_sweep(rng: np.random.Generator, B: int, n: int, T: int):
@@ -39,6 +43,21 @@ def make_sweep(rng: np.random.Generator, B: int, n: int, T: int):
         Problem(T=int(t), lower=base.lower, upper=base.upper, cost_tables=base.cost_tables)
         for t in sorted(Ts)
     ]
+
+
+def solve_fused(problems) -> np.ndarray:
+    """``(B, n)`` int64 schedules from the fused program on the problems'
+    own pack, with no shape bucketing."""
+    batch = ProblemBatch.from_problems(problems)
+    batch.validate()
+    b0 = remove_lower_limits(batch)
+    X, _ = solve_fused_batch_jax(
+        pack_problem(b0),
+        jnp.asarray(b0.T, jnp.int32),
+        int(b0.T.max()),
+        backend=resolve_backend("auto"),
+    )
+    return restore_lower_limits(batch, np.asarray(X).astype(np.int64))
 
 
 def _clear_jit_caches():
@@ -57,9 +76,9 @@ def time_sweep(problems, mode: str, reps: int = 3, cold: bool = False):
             _clear_jit_caches()
         t0 = time.perf_counter()
         if mode == "loop":
-            schedules = [solve_schedule_dp_jax(p) for p in problems]
+            schedules = [solve_fused([p])[0] for p in problems]
         else:
-            X = solve_schedule_dp_batch(problems)
+            X = solve_fused(problems)
             schedules = [X[b, : p.n] for b, p in enumerate(problems)]
         best = min(best, time.perf_counter() - t0)
     return best, schedules

@@ -1,6 +1,6 @@
 """Blocked min-plus kernel engine vs the dense oracle (DESIGN.md §12).
 
-Two production claims are measured and written to ``BENCH_kernels.json``:
+Two legs are measured and written to ``BENCH_kernels.json``:
 
   * **blocked vs dense** — one warm batched DP row update at a memory-bound
     shape (B=8, T=8192, W=512: the oracle materializes a ~134 MB candidate
@@ -9,18 +9,15 @@ Two production claims are measured and written to ``BENCH_kernels.json``:
     scripts/check_bench.py; ~4-8x measured on CPU), and the same run
     asserts bit-identical values AND argmins (``max_parity_err`` must be
     exactly 0).
-  * **fused vs two-dispatch** — a warm batched solve through the fused
-    DP+backtrack program (one jit call returning only ``(B, n)`` + K_last)
-    against the legacy chain of ``dp_tables_batch_jax`` +
-    ``backtrack_batch_jax`` (two dispatches, argmin matrix crossing the
-    boundary). ``speedup_fused_vs_twodispatch`` is reported info-only —
-    on small solves it hovers near 1x and swings with machine load.
+  * **fused solve** — a warm batched solve through the fused DP+backtrack
+    program (one jit call returning only ``(B, n)`` + K_last).
+    ``fused_solve_s`` is reported info-only.
 
 Run as::
 
     PYTHONPATH=src python benchmarks/bench_kernels.py [--smoke] [--out PATH]
 
-(The interpret-mode Pallas TPU/GPU kernels are validated in the test
+(The interpret-mode Pallas TPU kernel is validated in the test
 suite, not timed here — Python-interpreted kernel timing says nothing
 about hardware. The blocked jnp backend IS the CPU production path.)
 """
@@ -34,12 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import random_problem
-from repro.core.jax_dp import (
-    backtrack_batch_jax,
-    dp_tables_batch_jax,
-    pack_problem,
-    solve_fused_batch_jax,
-)
+from repro.core.jax_dp import pack_problem, solve_fused_batch_jax
 from repro.core.problem import ProblemBatch, remove_lower_limits
 from repro.kernels import minplus_blocked_batch, minplus_step_ref_batch
 
@@ -92,7 +84,7 @@ def bench_blocked_vs_dense(B: int, Tp: int, W: int, reps: int) -> dict:
     }
 
 
-def bench_fused_vs_twodispatch(B: int, n: int, T: int, reps: int) -> dict:
+def bench_fused_solve(B: int, n: int, T: int, reps: int) -> dict:
     rng = np.random.default_rng(1)
     probs = [
         random_problem(rng, n=n, T=int(t), regime="arbitrary", with_lower=False)
@@ -107,20 +99,11 @@ def bench_fused_vs_twodispatch(B: int, n: int, T: int, reps: int) -> dict:
         X, _ = solve_fused_batch_jax(costs, t_star, Tmax, backend="blocked")
         return np.asarray(jax.device_get(X))
 
-    def twodispatch():
-        _, I = dp_tables_batch_jax(costs, Tmax, backend="blocked")
-        return np.asarray(jax.device_get(backtrack_batch_jax(I, t_star, Tmax)))
-
-    np.testing.assert_array_equal(fused(), twodispatch())
-    fused_s = _bench(fused, reps)
-    two_s = _bench(twodispatch, reps)
     return {
         "solve_B": B,
         "solve_n": n,
         "solve_T": T,
-        "fused_solve_s": fused_s,
-        "twodispatch_solve_s": two_s,
-        "speedup_fused_vs_twodispatch": two_s / fused_s,
+        "fused_solve_s": _bench(fused, reps),
     }
 
 
@@ -128,7 +111,7 @@ def run_bench(smoke: bool) -> dict:
     # the acceptance shape: memory-bound for the oracle on any CPU
     reps = 3 if smoke else 10
     out = bench_blocked_vs_dense(B=8, Tp=8193, W=512, reps=reps)
-    out.update(bench_fused_vs_twodispatch(B=16, n=8, T=256 if smoke else 1024, reps=reps))
+    out.update(bench_fused_solve(B=16, n=8, T=256 if smoke else 1024, reps=reps))
     return out
 
 
@@ -145,7 +128,7 @@ def run():
         (
             f"fused_solve_B{r['solve_B']}_T{r['solve_T']}",
             r["fused_solve_s"] * 1e6,
-            f"speedup_vs_twodispatch={r['speedup_fused_vs_twodispatch']:.2f}x",
+            f"n={r['solve_n']}",
         ),
     ]
 

@@ -55,12 +55,10 @@ def run_bench(B: int, n: int, T: int, reps: int = 3, sharded: bool = True) -> di
     import numpy as np
 
     from repro.core import SweepEngine, make_sweep_mesh
-    from repro.core.jax_dp import solve_schedule_dp_batch
-
     try:  # package import (python -m benchmarks.run) or script (python benchmarks/bench_sweep.py)
-        from benchmarks.bench_batch import make_sweep
+        from benchmarks.bench_batch import make_sweep, solve_fused
     except ImportError:
-        from bench_batch import make_sweep
+        from bench_batch import make_sweep, solve_fused
 
     rng = np.random.default_rng(0)
     problems = make_sweep(rng, B, n, T)
@@ -74,7 +72,7 @@ def run_bench(B: int, n: int, T: int, reps: int = 3, sharded: bool = True) -> di
 
     # cached: drifted instances land in the same bucket -> warm executable
     cached_s = time_best(lambda: eng.solve(drift(problems, 1.01)), reps)
-    np.testing.assert_array_equal(X_cold, solve_schedule_dp_batch(problems))
+    np.testing.assert_array_equal(X_cold, solve_fused(problems))
 
     result = {
         "B": len(problems),

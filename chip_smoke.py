@@ -3,7 +3,7 @@
 user calls, and checks every answer against the repo's plain references.
 
     python chip_smoke.py [--seed 0]    # one chip: seven phases
-    python chip_smoke.py --chips 4     # both sharded engines vs one chip
+    python chip_smoke.py --chips 4     # the batch-mesh engine vs one chip
 
 One chip runs seven phases: the min-plus row update alone at the cross-silo
 shape, checked against the numpy DP and timed warm at ``B = 1`` and ``B = 8``;
@@ -14,8 +14,8 @@ per-phone uploads through the engine's regime split at the DP bucket
 integer reference, a fleet through
 ``Solver.solve_fleet``, two submitter threads through one
 ``SchedulerService``, and a three-round ``run_campaign``. ``--chips 4`` runs
-only the sharded DP: the batch-axis mesh and the class-axis ring, each
-compared bit for bit with a one-chip engine on ``devices[0]``.
+only the sharded DP: the batch axis over a mesh of every device, compared
+bit for bit with a one-chip engine on ``devices[0]``.
 
 Every phase prints one line with its shapes, its wall seconds (compiles
 included: this is not a benchmark) and its engine compile count. The last
@@ -364,21 +364,19 @@ def phase_sharded(seed: int, B: int, n: int, T: int, u_max: int):
 
     from repro.core.sweep import make_sweep_mesh
 
-    devices = jax.devices()  # both meshes span every device
+    devices = jax.devices()  # the mesh spans every device
     rng = np.random.default_rng(seed + 4)
     problems = [integer_problem(rng, n, T, u_max) for _ in range(B)]
     with jax.default_device(devices[0]):
         one = SweepEngine()
         X1 = one.solve(problems)
     batch_engine = SweepEngine(mesh=make_sweep_mesh())
-    ring_engine = SweepEngine(ring_mesh=make_sweep_mesh("ring"))
-    for name, eng in (("batch mesh", batch_engine), ("class ring", ring_engine)):
-        X = eng.solve(problems)
-        check(np.array_equal(X, X1), f"{name}: schedules differ from the one-chip engine")
+    X = batch_engine.solve(problems)
+    check(np.array_equal(X, X1), "batch mesh: schedules differ from the one-chip engine")
     for b in range(min(B, 4)):  # and the plain reference, on a few instances
         check(np.array_equal(X1[b, : problems[b].n], solve_schedule_dp(problems[b])),
               f"instance {b}: one-chip schedule differs from the numpy DP")
-    total = compiles(one) + compiles(batch_engine) + compiles(ring_engine)
+    total = compiles(one) + compiles(batch_engine)
     return f"B={B} n={n} T={T} W={u_max + 1} on {len(devices)} devices", total
 
 

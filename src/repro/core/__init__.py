@@ -25,12 +25,7 @@ from .costs import (
     superlinear_cost,
 )
 from .fleet import FleetSolution, PlanPolicy, cluster_clients, solve_fleet
-from .jax_dp import (
-    solve_fused_batch_jax,
-    solve_fused_batch_ring,
-    solve_schedule_dp_batch,
-    solve_schedule_dp_jax,
-)
+from .jax_dp import solve_fused_batch_jax
 from .marginal import marco, mardec, mardecun, marin
 from .marginal_jax import (
     marco_batch,
@@ -107,9 +102,7 @@ __all__ = [
     "solve_mc2mkp",
     "mc2mkp_matrices",
     "solve_schedule_dp",
-    "solve_schedule_dp_jax",
     "solve_fused_batch_jax",
-    "solve_schedule_dp_batch",
     "brute_force_schedule",
     "marin",
     "marco",
@@ -146,7 +139,6 @@ __all__ = [
     "PlanPolicy",
     "cluster_clients",
     "solve_fleet",
-    "solve_fused_batch_ring",
     "ParetoFrontier",
     "ParetoPoint",
     "pareto_frontier",

@@ -33,7 +33,6 @@ import numpy as np
 
 from . import baselines
 from ._deprecation import warn_deprecated
-from .jax_dp import solve_schedule_dp_jax
 from .marginal import marco, mardec, mardecun, marin
 from .marginal_jax import select_algorithm_batch
 from .mc2mkp import solve_schedule_dp
@@ -55,9 +54,11 @@ _DP_ALGORITHMS = {"dp", "dp_jax", "dp_batch", "dp_jax_pallas"}
 
 ALGORITHMS: Dict[str, Callable] = {
     "dp": solve_schedule_dp,
-    "dp_jax": solve_schedule_dp_jax,
-    # "pallas": the TPU kernel, interpreted on the CPU and compiled on a TPU
-    "dp_jax_pallas": lambda p: solve_schedule_dp_jax(p, backend="pallas"),
+    # the device DP: one instance through the shared sweep engine's fused
+    # program; "pallas" is the TPU kernel, interpreted on the CPU and
+    # compiled on a TPU
+    "dp_jax": lambda p: _solve_cached([p], None, None, split_regimes=False)[0],
+    "dp_jax_pallas": lambda p: _solve_cached([p], "pallas", None, split_regimes=False)[0],
     "marin": marin,
     "marco": marco,
     "mardecun": mardecun,

@@ -14,8 +14,9 @@ engine:
      warm bucket reuses the compiled executable — a campaign compiles once
      on round 1 and never again. Padding is *inert* (phantom instances /
      resources / BIG table entries; see :meth:`ProblemBatch.pad_to`), so
-     bucketed solves are bit-identical to uncached
-     :func:`~repro.core.jax_dp.solve_schedule_dp_batch`.
+     bucketed solves are bit-identical to the unbucketed fused program
+     :func:`~repro.core.jax_dp.solve_fused_batch_jax` on the unpadded
+     pack. The bucket executables are the one way the DP runs on a device.
   2. **shards** the batch axis: with a ``mesh``, inputs are placed with
      ``jax.sharding.NamedSharding`` over ``B`` (rounded up to a multiple of
      the axis size) and a ``shard_map`` runs the fused solve on each
@@ -67,7 +68,7 @@ from jax.sharding import AxisType, NamedSharding, PartitionSpec
 from ..kernels.minplus import band_steps
 from ..kernels.ops import resolve_backend
 from ._deprecation import warn_deprecated
-from .jax_dp import _solve_fused_batch, pack_problem, solve_fused_batch_ring
+from .jax_dp import _solve_fused_batch, pack_problem
 from .marginal_jax import (
     MARGINAL_BATCH_ALGORITHMS,
     marginal_select,
@@ -376,23 +377,13 @@ class SweepEngine:
       backend: min-plus kernel backend, forwarded to
         :func:`~repro.kernels.ops.minplus_step_batch`. The default "auto"
         resolves per hardware at construction (cpu -> "blocked",
-        tpu -> "pallas_tpu", gpu -> "pallas_gpu"); "ref" forces the dense
-        oracle.
+        tpu -> "pallas_tpu"); "ref" forces the dense oracle.
       max_entries: LRU capacity — distinct shape buckets kept warm.
       mesh: optional ``jax.sharding.Mesh``; when set, the batch axis is
         sharded over ``mesh_axis`` and ``B`` buckets round up to a multiple
         of that axis size.
       mesh_axis: mesh axis name to shard ``B`` over (default: the mesh's
         first axis).
-      ring_mesh: optional ``jax.sharding.Mesh``; when set, pure-DP buckets
-        shard the CLASS axis ``n`` as a device ring instead
-        (:func:`~repro.core.jax_dp.solve_fused_batch_ring`, DESIGN.md §16):
-        the DP row is handed around the ring while each device retains only
-        its own ``(n/D, B, T+1)`` argmin slab — bit-identical to the
-        unsharded scan, with per-device argmin memory divided by the ring
-        size. For ONE very wide problem (large ``n``); mutually exclusive
-        with ``mesh`` (large ``B``).
-      ring_axis: ring mesh axis name (default: the ring mesh's first axis).
     """
 
     def __init__(
@@ -401,28 +392,14 @@ class SweepEngine:
         max_entries: int = 64,
         mesh=None,
         mesh_axis: Optional[str] = None,
-        ring_mesh=None,
-        ring_axis: Optional[str] = None,
     ):
         if max_entries < 1:
             raise ValueError("max_entries must be >= 1")
-        if mesh is not None and ring_mesh is not None:
-            raise ValueError(
-                "mesh (batch-axis sharding) and ring_mesh (class-axis ring) "
-                "are mutually exclusive — build one engine per strategy"
-            )
         self.backend = resolve_backend(backend)
         self.max_entries = int(max_entries)
         self.mesh = mesh
         self.mesh_axis = mesh_axis or (mesh.axis_names[0] if mesh is not None else None)
         self._ndev = int(mesh.shape[self.mesh_axis]) if mesh is not None else 1
-        self.ring_mesh = ring_mesh
-        self.ring_axis = ring_axis or (
-            ring_mesh.axis_names[0] if ring_mesh is not None else None
-        )
-        self._ring_ndev = (
-            int(ring_mesh.shape[self.ring_axis]) if ring_mesh is not None else 1
-        )
         self._cache: OrderedDict = OrderedDict()
         self._hits = self._misses = self._compiles = self._evictions = 0
         self._compile_s = 0.0  # host seconds in calls that traced + compiled
@@ -521,7 +498,6 @@ class SweepEngine:
             return jax.jit(run_sel)
 
         _, _, _, Tb, _ = key
-        ring_mesh, ring_axis = self.ring_mesh, self.ring_axis
         solve = functools.partial(_solve_fused_batch, T=Tb, backend=backend)
         if self.mesh is not None:
             # each device solves its own rows (instances are independent);
@@ -541,12 +517,6 @@ class SweepEngine:
             # unless the entry is evicted and rebuilt).
             with self._lock:
                 self._compiles += 1
-            if ring_mesh is not None:
-                # class-axis ring (DESIGN.md §16): bit-identical rows, argmin
-                # slab sharded over the ring devices
-                return solve_fused_batch_ring(
-                    costs, t_star, Tb, backend, ring_mesh, ring_axis
-                )
             # fused DP + backtrack (DESIGN.md §12): one dispatch, and only
             # (X, K_last) leave the program — never the (n, B, T+1) argmins
             return solve(costs, t_star)
@@ -559,10 +529,6 @@ class SweepEngine:
         with _Phase(phases, "pack_s", "repro.engine.pack"):
             b0 = remove_lower_limits(batch)
             nb, Tb, Wb = _bucket_axes(b0)  # same math the coalescer keys on
-            if nb % self._ring_ndev:
-                # the ring splits the class axis evenly; pad the n-bucket up
-                # to a multiple of the ring size (phantom classes are inert)
-                nb = ((nb + self._ring_ndev - 1) // self._ring_ndev) * self._ring_ndev
             Bb = _next_pow2(b0.B)
             if Bb % self._ndev:
                 Bb = ((Bb + self._ndev - 1) // self._ndev) * self._ndev
@@ -652,9 +618,9 @@ class SweepEngine:
         that classify as pure-DP take exactly the default path (same
         buckets, same counters, plain :class:`SweepHandle`). The default
         ``False`` keeps the documented contract of bit-identity with
-        :func:`~repro.core.jax_dp.solve_schedule_dp_batch` for every
-        instance. MarDec sub-batches compute at dispatch time (host code
-        has no async seam).
+        :func:`~repro.core.jax_dp.solve_fused_batch_jax` on the unpadded
+        pack, for every instance. MarDec sub-batches compute at dispatch
+        time (host code has no async seam).
 
         The returned handle's ``phases`` dict (keys
         :data:`DISPATCH_PHASES`) holds this call's host seconds in all
@@ -714,12 +680,12 @@ class SweepEngine:
         return RegimeSplitHandle(batch.B, batch.n, parts)
 
     def solve(self, problems, split_regimes: bool = False) -> np.ndarray:
-        """Drop-in for :func:`~repro.core.jax_dp.solve_schedule_dp_batch`:
-        same inputs (sequence of :class:`Problem` or a prebuilt
-        :class:`ProblemBatch`), bit-identical ``(B, n)`` int64 schedules —
-        but warm buckets skip compilation entirely. With
-        ``split_regimes=True``, monotone instances ride the marginal fast
-        path instead of the DP (see :meth:`dispatch`)."""
+        """Blocking batched solve: a sequence of :class:`Problem` or a
+        prebuilt :class:`ProblemBatch` in, ``(B, n)`` int64 schedules out,
+        bit-identical to :func:`~repro.core.jax_dp.solve_fused_batch_jax`
+        on the unpadded pack — but warm buckets skip compilation entirely.
+        With ``split_regimes=True``, monotone instances ride the marginal
+        fast path instead of the DP (see :meth:`dispatch`)."""
         return self.dispatch(problems, split_regimes=split_regimes).result()
 
 

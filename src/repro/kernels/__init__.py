@@ -2,9 +2,8 @@
 
 ``blocked``: tiled jnp min-plus — the CPU production backend (cache-blocked
 BT x BW walk of the banded tropical convolution). ``minplus``: the Pallas
-TPU kernel (VMEM-budget-tuned output tiles). ``gpu``: the Pallas-GPU
-blocked variant. ``ref``: the dense jnp oracle used by the correctness
-sweeps. ``ops`` exposes the dispatching wrappers — ``backend="auto"``
+TPU kernel (VMEM-budget-tuned output tiles). ``ref``: the dense jnp oracle
+used by the correctness sweeps. ``ops`` exposes the dispatching wrappers — ``backend="auto"``
 selects per ``jax.default_backend()``.
 
 ``flash_attention``: FlashAttention-2-style fused attention (fwd + bwd) —
@@ -13,7 +12,6 @@ attention probabilities never touch HBM; selected via ``attn_impl='pallas'``.
 
 from .blocked import auto_block_sizes, minplus_blocked, minplus_blocked_batch
 from .flash_attention import flash_attention
-from .gpu import minplus_pallas_gpu, minplus_pallas_gpu_batch
 from .minplus import minplus_pallas, minplus_pallas_batch, tpu_tuned_bt
 from .ops import BIG, DISPATCH_TABLE, minplus_step, minplus_step_batch, resolve_backend
 from .ref import minplus_step_ref, minplus_step_ref_batch
@@ -25,8 +23,6 @@ __all__ = [
     "minplus_blocked_batch",
     "minplus_pallas",
     "minplus_pallas_batch",
-    "minplus_pallas_gpu",
-    "minplus_pallas_gpu_batch",
     "minplus_step_ref",
     "minplus_step_ref_batch",
     "auto_block_sizes",

@@ -11,17 +11,16 @@ resolves through :data:`DISPATCH_TABLE` keyed on ``jax.default_backend()``:
            |                | BT x BW walk, ~4-8x over the dense oracle
   tpu      | ``pallas_tpu`` | Pallas TPU kernel (`kernels/minplus.py`),
            |                | compiled by Mosaic, ``BT`` checked against VMEM
-  gpu      | ``pallas_gpu`` | Pallas-GPU blocked kernel (`kernels/gpu.py`)
 
-Any other platform raises: no backend is chosen for a device nobody has
-compiled for. The dense reference (``backend="ref"``) is retained as the
-small-shape oracle every backend is validated against. ``backend="pallas"``
-names the TPU kernel without naming the mode: on the CPU it runs in
-interpret mode (kernel debugging in tests), on a TPU it resolves to the
-compiled ``pallas_tpu``, and elsewhere it raises. Resolution happens at
-Python/trace time (``jax.default_backend()`` is not a traced value), so
-"auto" and its resolved backend share jit caches when callers resolve
-before specializing — see :func:`resolve_backend`.
+Any other platform, a GPU among them, raises: no backend is chosen for a
+device nobody has compiled for. The dense reference (``backend="ref"``) is
+retained as the small-shape oracle every backend is validated against.
+``backend="pallas"`` names the TPU kernel without naming the mode: on the
+CPU it runs in interpret mode (kernel debugging in tests), on a TPU it
+resolves to the compiled ``pallas_tpu``, and elsewhere it raises.
+Resolution happens at Python/trace time (``jax.default_backend()`` is not
+a traced value), so "auto" and its resolved backend share jit caches when
+callers resolve before specializing — see :func:`resolve_backend`.
 
 Both entry points take an optional ``band``: each row's band bound, one
 past its last finite cost entry (``None`` means the whole width ``W``). The
@@ -37,7 +36,6 @@ import jax
 import jax.numpy as jnp
 
 from .blocked import minplus_blocked, minplus_blocked_batch
-from .gpu import minplus_pallas_gpu, minplus_pallas_gpu_batch
 from .minplus import minplus_pallas, minplus_pallas_batch, tpu_tuned_bt
 from .ref import BIG, minplus_step_ref, minplus_step_ref_batch
 
@@ -51,9 +49,9 @@ __all__ = [
 ]
 
 # jax.default_backend() platform -> kernel backend
-DISPATCH_TABLE = {"cpu": "blocked", "tpu": "pallas_tpu", "gpu": "pallas_gpu"}
+DISPATCH_TABLE = {"cpu": "blocked", "tpu": "pallas_tpu"}
 
-BACKENDS = ("ref", "blocked", "pallas", "pallas_tpu", "pallas_gpu")
+BACKENDS = ("ref", "blocked", "pallas", "pallas_tpu")
 
 
 def resolve_backend(backend: str | None = "auto") -> str:
@@ -91,8 +89,6 @@ def minplus_step(kprev: jnp.ndarray, cost: jnp.ndarray, backend: str = "auto", b
     if backend in ("pallas", "pallas_tpu"):
         bt = tpu_tuned_bt(kprev.shape[0], cost.shape[0])
         return minplus_pallas(kprev, cost, band, BT=bt, interpret=backend == "pallas")
-    if backend == "pallas_gpu":
-        return minplus_pallas_gpu(kprev, cost, interpret=False)
     raise ValueError(f"unknown backend {backend!r}")
 
 
@@ -109,6 +105,4 @@ def minplus_step_batch(
     if backend in ("pallas", "pallas_tpu"):
         bt = tpu_tuned_bt(kprev.shape[1], cost.shape[1])
         return minplus_pallas_batch(kprev, cost, band, BT=bt, interpret=backend == "pallas")
-    if backend == "pallas_gpu":
-        return minplus_pallas_gpu_batch(kprev, cost, interpret=False)
     raise ValueError(f"unknown backend {backend!r}")

@@ -2,11 +2,12 @@
 vmapped/stacked min-plus DP, batched backtracking, dispatch, sweeps, and the
 FL scenario-planning hook.
 
-Core claim under test: ``solve_schedule_dp_batch`` over B stacked instances
-is EQUIVALENT to looping the per-instance solvers — bit-identical schedules
-vs ``solve_schedule_dp_jax`` (same float32 program, same tie-breaking) and
-equal assignments/costs vs the numpy ``solve_schedule_dp``, across mixed
-regimes and ragged ``n`` / ``U_i`` / ``T``.
+Core claim under test: the batched DP over B stacked instances (the shared
+sweep engine) is EQUIVALENT to solving each instance alone — bit-identical
+schedules vs the fused program on the instance's own pack (same float32
+program, same tie-breaking) and equal assignments/costs vs the numpy
+``solve_schedule_dp``, across mixed regimes and ragged ``n`` / ``U_i`` /
+``T``.
 """
 
 import numpy as np
@@ -16,17 +17,24 @@ from repro.core import (
     Problem,
     ProblemBatch,
     deadline_sweep,
+    default_engine,
     random_problem,
     remove_lower_limits,
     schedule_batch,
     solve_schedule_dp,
-    solve_schedule_dp_batch,
-    solve_schedule_dp_jax,
     total_cost,
     total_cost_batch,
     validate_schedule,
     validate_schedule_batch,
 )
+
+from _fused_ref import solve_one_unbucketed
+
+
+def solve_batch(problems, backend="auto"):
+    """``(B, n)`` schedules from the shared sweep engine's batched DP."""
+    return default_engine(backend).solve(problems)
+
 
 REGIMES = ("arbitrary", "linear", "increasing", "decreasing")
 
@@ -105,7 +113,7 @@ def test_problem_batch_validation_errors():
 def test_batch_dp_equals_per_instance(B, seed):
     rng = np.random.default_rng(100 + seed)
     probs = random_mixed_problems(rng, B)
-    X = solve_schedule_dp_batch(probs)
+    X = solve_batch(probs)
     assert X.shape == (B, max(p.n for p in probs))
     for b, p in enumerate(probs):
         row = X[b, : p.n]
@@ -113,7 +121,7 @@ def test_batch_dp_equals_per_instance(B, seed):
         # padded resources are always assigned 0
         assert np.all(X[b, p.n :] == 0)
         # bit-identical vs the per-instance jitted solver
-        assert np.array_equal(row, solve_schedule_dp_jax(p)), (b, row)
+        assert np.array_equal(row, solve_one_unbucketed(p)), (b, row)
         # equal cost (and, with float32-safe tables, equal schedule) vs numpy
         x_np = solve_schedule_dp(p)
         assert total_cost(p, row) == pytest.approx(total_cost(p, x_np), rel=1e-5)
@@ -123,7 +131,7 @@ def test_batch_dp_prebuilt_batch_and_costs():
     rng = np.random.default_rng(42)
     probs = random_mixed_problems(rng, 5)
     batch = ProblemBatch.from_problems(probs)
-    X = solve_schedule_dp_batch(batch)
+    X = solve_batch(batch)
     validate_schedule_batch(batch, X)
     tc = total_cost_batch(batch, X)
     for b, p in enumerate(probs):
@@ -139,17 +147,17 @@ def test_batch_dp_ragged_T_uses_per_instance_t_star():
         Problem(T=t, lower=base.lower, upper=base.upper, cost_tables=base.cost_tables)
         for t in (1, 7, 23, 40)
     ]
-    X = solve_schedule_dp_batch(probs)
+    X = solve_batch(probs)
     for b, p in enumerate(probs):
         assert int(X[b].sum()) == p.T
-        assert np.array_equal(X[b], solve_schedule_dp_jax(p))
+        assert np.array_equal(X[b], solve_one_unbucketed(p))
 
 
 def test_batch_dp_with_lower_limits():
     rng = np.random.default_rng(8)
     probs = [random_problem(rng, n=4, T=16, regime="arbitrary") for _ in range(6)]
     assert any(int(p.lower.sum()) > 0 for p in probs)
-    X = solve_schedule_dp_batch(probs)
+    X = solve_batch(probs)
     for b, p in enumerate(probs):
         validate_schedule(p, X[b, : p.n])
         assert total_cost(p, X[b, : p.n]) == pytest.approx(
@@ -214,8 +222,8 @@ def test_batched_ref_matches_unbatched_rows():
 def test_batch_dp_pallas_backend_end_to_end():
     rng = np.random.default_rng(9)
     probs = [random_problem(rng, n=3, T=10, regime=r) for r in ("arbitrary", "decreasing")]
-    Xp = solve_schedule_dp_batch(probs, backend="pallas")
-    Xr = solve_schedule_dp_batch(probs, backend="ref")
+    Xp = solve_batch(probs, backend="pallas")
+    Xr = solve_batch(probs, backend="ref")
     for b, p in enumerate(probs):
         validate_schedule(p, Xp[b, : p.n])
         assert total_cost(p, Xp[b, : p.n]) == pytest.approx(

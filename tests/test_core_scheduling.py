@@ -20,6 +20,7 @@ except ImportError:  # clean container: deterministic fallback sampler
 from repro.core import (
     ItemClass,
     Problem,
+    Solver,
     brute_force_schedule,
     marco,
     mardec,
@@ -35,7 +36,6 @@ from repro.core import (
     select_algorithm,
     solve_mc2mkp,
     solve_schedule_dp,
-    solve_schedule_dp_jax,
     total_cost,
     uniform,
     validate_schedule,
@@ -75,7 +75,7 @@ def test_dp_optimal_vs_brute_force(p):
 @settings(max_examples=40, deadline=None)
 @given(instances())
 def test_jax_dp_matches_numpy_dp(p):
-    xj = solve_schedule_dp_jax(p)
+    xj = Solver().solve(p, algorithm="dp_jax").schedule
     validate_schedule(p, xj)
     assert total_cost(p, xj) == pytest.approx(total_cost(p, solve_schedule_dp(p)), rel=1e-5)
 

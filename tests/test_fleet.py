@@ -10,9 +10,6 @@ Covers the two-level decomposition in ``repro.core.fleet``:
   beats it (the flat DP is optimal);
 - determinism: k-means labels are a pure function of (problem, seed) with
   canonical first-appearance numbering;
-- ring sharding: the class-axis ring DP is bit-identical to the unsharded
-  fused DP on a forced-8-device host (subprocess, same pattern as
-  test_sweep_engine.py);
 - PlanPolicy: FederatedServer legacy kwargs are warn-once shims that are
   bit-identical to the policy= spelling, and fleet-mode round planning
   goes through Solver.solve_fleet.
@@ -20,10 +17,6 @@ Covers the two-level decomposition in ``repro.core.fleet``:
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
 import warnings
 
 import numpy as np
@@ -46,8 +39,6 @@ from repro.core import (
 )
 from repro.core._deprecation import reset_deprecation_warnings
 from repro.core.fleet import PlanPolicy
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -252,92 +243,3 @@ def test_fleet_mode_round_plan_is_a_valid_schedule():
 def test_plan_policy_validation():
     with pytest.raises(ValueError, match="frontier_mode requires time_tables"):
         PlanPolicy(frontier_mode="knee")
-
-
-# ---------------------------------------------------------------------------
-# class-axis ring sharding: bit-identical on a forced-8-device host
-# ---------------------------------------------------------------------------
-
-
-def test_ring_sharded_dp_bit_identical_forced_8_devices():
-    src = textwrap.dedent(
-        """
-        import os
-        os.environ["XLA_FLAGS"] = (
-            "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
-        )
-        import sys
-        sys.path[:0] = %r
-        import numpy as np
-        import jax
-        from repro.core import (SweepEngine, make_sweep_mesh, random_problem,
-                                solve_schedule_dp_batch)
-
-        assert len(jax.devices()) == 8, jax.devices()
-        rng = np.random.default_rng(7)
-        regimes = ("arbitrary", "linear", "increasing", "decreasing")
-        probs = [
-            random_problem(rng, n=int(rng.integers(3, 12)), T=int(rng.integers(8, 30)),
-                           regime=regimes[b %% len(regimes)])
-            for b in range(6)
-        ]
-        mesh = make_sweep_mesh()
-        eng_ring = SweepEngine(ring_mesh=mesh)
-        X_ring = eng_ring.solve(probs)
-        X_ref = SweepEngine().solve(probs)
-        X_un = solve_schedule_dp_batch(probs)
-        assert np.array_equal(X_ring, X_ref), "ring-sharded != unsharded"
-        assert np.array_equal(X_ring, X_un), "ring-sharded != uncached"
-
-        # argmin slabs narrowed to int8 (W 64) and int16 (W 256) on every
-        # ring device: schedules equal the int32 argmin path's
-        import jax.numpy as jnp
-        from _int32_dp import fused_int32
-        from repro.core import Problem
-        from repro.core.jax_dp import pack_problem
-        from repro.core.problem import ProblemBatch
-
-        for U in (63, 255):
-            wide = []
-            for b in range(3):
-                n = int(rng.integers(3, 12))
-                marg = rng.integers(1, 40, size=(n, U)).astype(float)
-                marg[0] = -1.0  # client 0's curve falls: it takes the whole band
-                tables = [np.concatenate([[500.0], 500.0 + np.cumsum(m)]) for m in marg]
-                T = int(rng.integers(U, n * U * 2 // 3 + 1))
-                wide.append(Problem(T=T, lower=np.zeros(n, np.int64),
-                                    upper=np.full(n, U, np.int64), cost_tables=tables))
-            X_wide = SweepEngine(ring_mesh=mesh).solve(wide)
-            b0 = ProblemBatch.from_problems(wide)
-            X32, _ = fused_int32(pack_problem(b0), jnp.asarray(b0.T, jnp.int32),
-                                 int(b0.T.max()), "blocked")
-            X32 = np.asarray(X32)
-            assert np.array_equal(X_wide, X32), f"ring at U={U} != int32 argmin path"
-            assert int(X32.max()) == U
-
-        # mesh and ring_mesh are mutually exclusive
-        try:
-            SweepEngine(mesh=mesh, ring_mesh=mesh)
-        except ValueError:
-            pass
-        else:
-            raise AssertionError("mesh+ring_mesh should raise")
-
-        # fleet solve riding on the ring engine stays exact at q=1
-        from repro.core import Solver
-        p = random_problem(np.random.default_rng(3), n=16, T=40)
-        fsol = Solver(engine=SweepEngine(ring_mesh=make_sweep_mesh())).solve_fleet(
-            p, clusters=4, quantum=1)
-        flat = Solver(engine=SweepEngine()).solve([p], algorithm="dp_batch")
-        assert abs(fsol.objective - float(flat.objectives[0])) <= 1e-6
-        print("RING_OK")
-        """
-        % [os.path.join(REPO, "src"), os.path.dirname(os.path.abspath(__file__))]
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", src], capture_output=True, text=True, timeout=420
-    )
-    assert proc.returncode == 0, (
-        f"subprocess failed:\nSTDOUT:{proc.stdout}\nSTDERR:{proc.stderr[-3000:]}"
-    )
-    assert "RING_OK" in proc.stdout

@@ -5,9 +5,8 @@ Claims under test:
     first-min argmins — over ragged (B, T, W) and odd/pathological block
     sizes, including BIG saturation and all-BIG rows (property-based, with
     the hypothesis fallback);
-  * the Pallas-GPU blocked kernel (interpret mode) matches the oracle too;
-  * the fused single-dispatch solver returns exactly what the legacy
-    two-dispatch chain returns, plus a correct K_last row;
+  * the fused single-dispatch solver returns exactly what the class scan
+    and the backtrack return as two programs, plus a correct K_last row;
   * ``SweepEngine`` on the fused path still compiles once per bucket, and
     its handles expose per-instance objectives for free;
   * the per-hardware dispatch table resolves "auto" to the blocked backend
@@ -31,13 +30,12 @@ from repro.core import (
     SweepEngine,
     random_problem,
     solve_schedule_dp,
-    solve_schedule_dp_batch,
     total_cost,
 )
 from repro.core.jax_dp import (
+    _backtrack_batch,
+    _dp_tables_batch,
     argmin_dtype,
-    backtrack_batch_jax,
-    dp_tables_batch_jax,
     pack_problem,
     solve_fused_batch_jax,
 )
@@ -47,14 +45,19 @@ from repro.kernels import (
     DISPATCH_TABLE,
     auto_block_sizes,
     minplus_blocked_batch,
-    minplus_pallas_gpu_batch,
     minplus_step_batch,
     minplus_step_ref_batch,
     resolve_backend,
     tpu_tuned_bt,
 )
 
+from _fused_ref import solve_unbucketed
 from _int32_dp import fused_int32
+
+# the class scan and the backtrack as two programs, the argmin matrix
+# crossing between them
+dp_tables = jax.jit(_dp_tables_batch, static_argnames=("T", "backend"))
+backtrack = jax.jit(_backtrack_batch, static_argnames=("T",))
 
 
 def random_band_inputs(rng, B, Tp, W, frac_inf=0.3):
@@ -146,16 +149,6 @@ def test_blocked_all_big_saturation_and_argmin_convention():
     assert_bit_identical((bv, bi), minplus_step_ref_batch(kprev, cost))
 
 
-@pytest.mark.parametrize("Tp,W,BT,BW", [(64, 16, 32, 8), (255, 130, 256, 64)])
-def test_pallas_gpu_matches_dense_interpret(Tp, W, BT, BW):
-    rng = np.random.default_rng(Tp + W)
-    kprev, cost = random_band_inputs(rng, 2, Tp, W)
-    assert_bit_identical(
-        minplus_pallas_gpu_batch(kprev, cost, BT=BT, BW=BW, interpret=True),
-        minplus_step_ref_batch(kprev, cost),
-    )
-
-
 # ---------------------------------------------------------------------------
 # dispatch table
 # ---------------------------------------------------------------------------
@@ -165,7 +158,7 @@ def test_pallas_gpu_matches_dense_interpret(Tp, W, BT, BW):
     jax.default_backend() != "cpu", reason="asserts the CPU row of the dispatch table"
 )
 def test_dispatch_table_resolves_auto_per_hardware():
-    assert DISPATCH_TABLE == {"cpu": "blocked", "tpu": "pallas_tpu", "gpu": "pallas_gpu"}
+    assert DISPATCH_TABLE == {"cpu": "blocked", "tpu": "pallas_tpu"}
     assert resolve_backend("auto") == "blocked"
     assert resolve_backend(None) == "blocked"
     assert resolve_backend("ref") == "ref"  # explicit names pass through
@@ -186,11 +179,12 @@ def test_resolve_backend_interprets_only_on_cpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert resolve_backend("auto") == "pallas_tpu"
     assert resolve_backend("pallas") == "pallas_tpu"
-    monkeypatch.setattr(jax, "default_backend", lambda: "metal")
-    with pytest.raises(ValueError, match="no min-plus backend"):
-        resolve_backend("auto")
-    with pytest.raises(ValueError, match="runs on cpu or tpu"):
-        resolve_backend("pallas")
+    for platform in ("gpu", "metal"):
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        with pytest.raises(ValueError, match="no min-plus backend"):
+            resolve_backend("auto")
+        with pytest.raises(ValueError, match="runs on cpu or tpu"):
+            resolve_backend("pallas")
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert resolve_backend("pallas") == "pallas"
 
@@ -255,8 +249,8 @@ def test_fused_solver_matches_twodispatch_and_numpy_dp():
     t_star = jnp.asarray(b0.T, dtype=jnp.int32)
     for backend in ("blocked", "ref"):
         X, k_last = solve_fused_batch_jax(costs, t_star, Tmax, backend=backend)
-        k2, I = dp_tables_batch_jax(costs, Tmax, backend=backend)
-        X2 = backtrack_batch_jax(I, t_star, Tmax)
+        k2, I = dp_tables(costs, T=Tmax, backend=backend)
+        X2 = backtrack(I, t_star, T=Tmax)
         np.testing.assert_array_equal(np.asarray(X), np.asarray(X2))
         np.testing.assert_array_equal(np.asarray(k_last), np.asarray(k2))
         assert X.shape == (b0.B, b0.n) and k_last.shape == (b0.B, Tmax + 1)
@@ -291,7 +285,7 @@ def test_narrow_argmins_are_bit_identical_to_int32(W, backend):
     np.testing.assert_array_equal(np.asarray(X), np.asarray(X32))
     np.testing.assert_array_equal(np.asarray(k_last), np.asarray(k32))
     assert np.asarray(X).dtype == np.int32 and int(np.asarray(X32).max()) == W - 1
-    _, I = dp_tables_batch_jax(jnp.asarray(costs), T, backend=backend)
+    _, I = dp_tables(jnp.asarray(costs), T=T, backend=backend)
     assert I.dtype == argmin_dtype(W) == (jnp.int8 if W <= 128 else jnp.int16)
 
 
@@ -307,13 +301,13 @@ def test_batched_solver_blocked_bit_identical_to_ref_end_to_end():
     rng = np.random.default_rng(23)
     probs = _random_sweep(rng, 9)
     np.testing.assert_array_equal(
-        solve_schedule_dp_batch(probs, backend="blocked"),
-        solve_schedule_dp_batch(probs, backend="ref"),
+        solve_unbucketed(probs, backend="blocked"),
+        solve_unbucketed(probs, backend="ref"),
     )
     # and "auto" matches its resolved concrete backend ("blocked" on CPU)
     np.testing.assert_array_equal(
-        solve_schedule_dp_batch(probs, backend="auto"),
-        solve_schedule_dp_batch(probs, backend=resolve_backend("auto")),
+        solve_unbucketed(probs, backend="auto"),
+        solve_unbucketed(probs, backend=resolve_backend("auto")),
     )
 
 
@@ -328,7 +322,7 @@ def test_sweep_engine_fused_path_compiles_once_per_bucket():
     eng = SweepEngine()  # backend="auto" resolves per hardware at init
     assert eng.backend == resolve_backend("auto")
     X = eng.solve(probs)
-    np.testing.assert_array_equal(X, solve_schedule_dp_batch(probs))
+    np.testing.assert_array_equal(X, solve_unbucketed(probs))
     # drifted costs, same shapes: 2 more solves, still ONE compilation
     for f in (1.05, 0.93):
         drifted = [
@@ -341,7 +335,7 @@ def test_sweep_engine_fused_path_compiles_once_per_bucket():
             for p in probs
         ]
         np.testing.assert_array_equal(
-            eng.solve(drifted), solve_schedule_dp_batch(drifted)
+            eng.solve(drifted), solve_unbucketed(drifted)
         )
     s = eng.cache_stats()
     assert s["compiles"] == 1 and s["misses"] == 1 and s["hits"] == 2, s

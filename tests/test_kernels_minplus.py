@@ -7,8 +7,7 @@ The kernel runs in interpret mode on the CPU; the oracle is
 import numpy as np
 import pytest
 
-from repro.core import Problem, solve_schedule_dp, total_cost
-from repro.core.jax_dp import solve_schedule_dp_jax
+from repro.core import Problem, Solver, solve_schedule_dp, total_cost
 from repro.kernels import (
     BIG,
     minplus_pallas,
@@ -125,7 +124,7 @@ def test_dp_via_pallas_backend_end_to_end():
 
     for regime in ("arbitrary", "decreasing", "increasing"):
         p = random_problem(rng, n=5, T=40, regime=regime)
-        x_pal = solve_schedule_dp_jax(p, backend="pallas")
+        x_pal = Solver().solve(p, algorithm="dp_jax_pallas").schedule
         x_np = solve_schedule_dp(p)
         assert total_cost(p, x_pal) == pytest.approx(total_cost(p, x_np), rel=1e-5)
 

@@ -47,10 +47,11 @@ from repro.core import (
     select_algorithm,
     select_algorithm_batch,
     solve_schedule_dp,
-    solve_schedule_dp_batch,
     total_cost,
     validate_schedule,
 )
+
+from _fused_ref import solve_unbucketed
 
 # one fixed kernel envelope for most parity tests: every batch is padded to
 # it, so the selection kernel compiles exactly once for the whole module
@@ -162,7 +163,7 @@ def test_marginal_batch_dp_objective_equality():
     rng = np.random.default_rng(7)
     probs = [integer_increasing_problem(rng, n=4, T=14, max_marginal=5) for _ in range(6)]
     X = marin_batch(probs)
-    X_dp = solve_schedule_dp_batch(probs)
+    X_dp = solve_unbucketed(probs)
     for b, p in enumerate(probs):
         validate_schedule(p, X[b, : p.n])
         assert total_cost(p, X[b, : p.n]) == total_cost(p, X_dp[b, : p.n])
@@ -336,17 +337,17 @@ def test_split_engine_handle_and_bucketing():
     h_dp = eng.dispatch(dp_probs, split_regimes=True)
     assert hasattr(h_dp, "k_last") and h_dp.k_last().shape[0] == len(dp_probs)
     np.testing.assert_array_equal(
-        h_dp.result(), solve_schedule_dp_batch(dp_probs)
+        h_dp.result(), solve_unbucketed(dp_probs)
     )
 
 
 def test_unsplit_default_unchanged():
     """The default (no split) engine contract is untouched: bit-identical
-    to the uncached batched DP even on monotone instances."""
+    to the unbucketed fused DP even on monotone instances."""
     rng = np.random.default_rng(13)
     probs = _mixed_problems(rng, B=6)
     X = SweepEngine().solve(probs)
-    np.testing.assert_array_equal(X, solve_schedule_dp_batch(probs))
+    np.testing.assert_array_equal(X, solve_unbucketed(probs))
 
 
 @pytest.mark.slow
@@ -368,6 +369,6 @@ def test_wide_sweep_parity_slow():
     X = marin_batch(probs)
     for b, p in enumerate(probs):
         assert np.array_equal(X[b, : p.n], marin(p))
-    X_dp = solve_schedule_dp_batch(probs)
+    X_dp = solve_unbucketed(probs)
     for b, p in enumerate(probs):
         assert total_cost(p, X[b, : p.n]) == total_cost(p, X_dp[b, : p.n])

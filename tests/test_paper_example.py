@@ -5,10 +5,10 @@ import pytest
 
 from repro.core import (
     Problem,
+    Solver,
     brute_force_schedule,
     schedule,
     solve_schedule_dp,
-    solve_schedule_dp_jax,
     total_cost,
 )
 
@@ -49,7 +49,7 @@ def test_example_matches_brute_force():
 def test_example_jax_dp_matches():
     for T in (5, 8, 12):
         p = paper_problem(T)
-        x = solve_schedule_dp_jax(p)
+        x = Solver().solve(p, algorithm="dp_jax").schedule
         assert total_cost(p, x) == pytest.approx(total_cost(p, solve_schedule_dp(p)))
 
 

@@ -62,8 +62,7 @@ CORE_ALL = {
     "restore_lower_limits", "schedule", "schedule_batch",
     "schedule_with_deadline", "select_algorithm", "select_algorithm_batch",
     "solve_dp_batch_cached", "solve_fleet", "solve_fused_batch_jax",
-    "solve_fused_batch_ring", "solve_mc2mkp", "solve_schedule_batch_cached",
-    "solve_schedule_dp", "solve_schedule_dp_batch", "solve_schedule_dp_jax",
+    "solve_mc2mkp", "solve_schedule_batch_cached", "solve_schedule_dp",
     "sublinear_cost", "superlinear_cost", "tighten_for_deadline",
     "total_cost", "total_cost_batch", "uniform", "validate_schedule",
     "validate_schedule_batch",

@@ -21,7 +21,7 @@ import time
 import numpy as np
 import pytest
 
-from repro.core import Problem, ProblemBatch, SweepEngine, random_problem, solve_schedule_dp_batch
+from repro.core import Problem, ProblemBatch, SweepEngine, random_problem
 from repro.core.sweep import request_bucket
 from repro.serve import (
     SchedulerService,
@@ -32,6 +32,8 @@ from repro.serve import (
     pow2_ladder,
     warm_batch,
 )
+
+from _fused_ref import solve_unbucketed
 
 REGIMES = ("arbitrary", "linear", "increasing", "decreasing")
 
@@ -70,9 +72,9 @@ def test_combine_batches_slices_and_padding_inert():
     groups = [ProblemBatch.from_problems(ragged_problems(rng, k)) for k in (1, 3, 2)]
     combined, slices = combine_batches(groups)
     assert combined.B == 6 and slices == [(0, 1), (1, 4), (4, 6)]
-    X_all = solve_schedule_dp_batch(combined)
+    X_all = solve_unbucketed(combined)
     for g, (lo, hi) in zip(groups, slices):
-        np.testing.assert_array_equal(X_all[lo:hi, : g.n], solve_schedule_dp_batch(g))
+        np.testing.assert_array_equal(X_all[lo:hi, : g.n], solve_unbucketed(g))
 
 
 def test_pow2_ladder_and_warm_batch():
@@ -85,7 +87,7 @@ def test_pow2_ladder_and_warm_batch():
     assert request_bucket(wb) == (4, 16, 8)  # lands in the spec's bucket
     mono = warm_batch(4, 12, 8, B=2, regime="increasing")
     assert request_bucket(mono) == (4, 16, 8)
-    solve_schedule_dp_batch(wb)  # feasible by construction
+    solve_unbucketed(wb)  # feasible by construction
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,9 @@ Claims under test:
     yields per-instance views whose fields match, including through the
     serve layer (``Solver(service=...)``);
   * substrate conflicts (engine vs backend, engine vs service.engine) raise
-    at construction.
+    at construction;
+  * the single-instance device DP names (``dp_jax``, ``dp_jax_pallas``) run
+    the shared sweep engine's fused program: one compile, then warm hits.
 """
 
 import warnings
@@ -33,8 +35,11 @@ from repro.core import (
     total_cost,
 )
 from repro.core._deprecation import reset_deprecation_warnings
+from repro.core.sweep import default_engine, reset_default_engines
 from repro.core.scheduler import _schedule
 from repro.serve import SchedulerService
+
+from _fused_ref import solve_one_unbucketed
 
 REGIMES = ("arbitrary", "linear", "increasing", "decreasing")
 
@@ -197,3 +202,17 @@ def test_solution_objective_is_exact_float64():
     )
     sol = Solver().solve(p)
     assert sol.objective == total_cost(p, sol.schedule)  # host f64, exact
+
+
+@pytest.mark.parametrize("name,backend", [("dp_jax", "auto"), ("dp_jax_pallas", "pallas")])
+def test_device_dp_names_run_the_shared_engine(name, backend):
+    reset_default_engines()
+    p = random_problem(np.random.default_rng(17), n=5, T=30, regime="arbitrary")
+    want = solve_one_unbucketed(p, backend)
+    np.testing.assert_array_equal(Solver().solve(p, algorithm=name).schedule, want)
+    s = default_engine(backend).cache_stats()
+    assert (s["compiles"], s["misses"], s["hits"]) == (1, 1, 0), s
+    np.testing.assert_array_equal(Solver().solve(p, algorithm=name).schedule, want)
+    s = default_engine(backend).cache_stats()
+    assert (s["compiles"], s["misses"], s["hits"]) == (1, 1, 1), s
+    reset_default_engines()

@@ -1,14 +1,15 @@
 """Sweep engine (DESIGN.md §10): shape-bucketed compile cache + sharding.
 
 Claims under test:
-  * bucketed/padded cached solves are BIT-IDENTICAL to the uncached
-    :func:`solve_schedule_dp_batch` (padding is inert);
+  * bucketed/padded cached solves are BIT-IDENTICAL to the fused program
+    :func:`solve_fused_batch_jax` on the unpadded pack (padding is inert);
   * a 3-round FL campaign with per-round scenario planning and drifting
     energy estimates performs exactly ONE DP compilation;
   * crossing a bucket boundary recompiles, staying inside one doesn't;
   * the LRU evicts and honestly re-counts compiles on re-entry;
   * sharding the batch axis over 8 forced host devices changes nothing
-    about the schedules (subprocess, same pattern as test_distribution.py).
+    about the schedules, int8 and int16 argmins and the fleet solve
+    included (subprocess, same pattern as test_distribution.py).
 """
 
 import os
@@ -28,10 +29,11 @@ from repro.core import (
     random_problem,
     schedule_batch,
     solve_schedule_dp,
-    solve_schedule_dp_batch,
     total_cost,
     validate_schedule,
 )
+
+from _fused_ref import solve_unbucketed
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REGIMES = ("arbitrary", "linear", "increasing", "decreasing")
@@ -81,8 +83,8 @@ def test_problem_batch_pad_to_is_inert():
     assert (padded.B, padded.n, padded.W) == (8, 8, batch.W + 5)
     # real region is untouched, phantoms solve to all-zero rows
     np.testing.assert_array_equal(padded.costs[: batch.B, : batch.n, : batch.W], batch.costs)
-    X = solve_schedule_dp_batch(padded)
-    X_ref = solve_schedule_dp_batch(batch)
+    X = solve_unbucketed(padded)
+    X_ref = solve_unbucketed(batch)
     np.testing.assert_array_equal(X[: batch.B, : batch.n], X_ref)
     assert np.all(X[batch.B :] == 0) and np.all(X[:, batch.n :] == 0)
     # no-op and shrink behaviour
@@ -102,12 +104,12 @@ def test_cached_solve_bit_identical_to_uncached(seed):
     probs = random_mixed_problems(rng, 9)
     eng = SweepEngine()
     X = eng.solve(probs)
-    np.testing.assert_array_equal(X, solve_schedule_dp_batch(probs))
+    np.testing.assert_array_equal(X, solve_unbucketed(probs))
     assert eng.cache_stats()["compiles"] == 1
     # drifted costs, same shapes: cache hit, still exact
     probs2 = drift(probs, 1.07)
     X2 = eng.solve(probs2)
-    np.testing.assert_array_equal(X2, solve_schedule_dp_batch(probs2))
+    np.testing.assert_array_equal(X2, solve_unbucketed(probs2))
     s = eng.cache_stats()
     per_bucket = s.pop("per_bucket_hits")
     assert s.pop("compile_s") > 0  # the one call that traced and compiled
@@ -160,7 +162,7 @@ def test_lru_eviction_and_recompile():
     X = eng.solve(small)  # re-enter the evicted bucket: honest recompile
     s = eng.cache_stats()
     assert s["compiles"] == 3 and s["hits"] == 0
-    np.testing.assert_array_equal(X, solve_schedule_dp_batch(small))
+    np.testing.assert_array_equal(X, solve_unbucketed(small))
     eng.clear()
     assert eng.cache_stats()["compiles"] == 0 and eng.cache_stats()["entries"] == 0
 
@@ -187,12 +189,12 @@ def test_lru_evicts_oldest_of_many_buckets():
     s = eng.cache_stats()
     assert s["compiles"] == 3 and s["hits"] == 2
     np.testing.assert_array_equal(X, Xa)
-    np.testing.assert_array_equal(X, solve_schedule_dp_batch(bucket_a))
+    np.testing.assert_array_equal(X, solve_unbucketed(bucket_a))
 
     eng.solve(bucket_b)  # b was evicted: honest recompile, exact again
     s = eng.cache_stats()
     assert s["compiles"] == 4 and s["evictions"] == 2, s
-    np.testing.assert_array_equal(eng.solve(bucket_b), solve_schedule_dp_batch(bucket_b))
+    np.testing.assert_array_equal(eng.solve(bucket_b), solve_unbucketed(bucket_b))
     # per-bucket hit attribution saw every warm re-solve
     assert sum(s["per_bucket_hits"].values()) == s["hits"]
 
@@ -208,7 +210,7 @@ def test_dispatch_thread_safe_under_concurrent_producers():
     batches = []
     for i in range(8):
         probs = random_mixed_problems(rng, int(rng.integers(1, 5)))
-        batches.append((ProblemBatch.from_problems(probs), solve_schedule_dp_batch(probs)))
+        batches.append((ProblemBatch.from_problems(probs), solve_unbucketed(probs)))
 
     eng = SweepEngine()
     eng.solve(batches[0][0])  # warm one bucket; others trace under contention
@@ -355,11 +357,12 @@ def test_sharded_solve_matches_single_device_bit_identical():
             "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
         )
         import sys
-        sys.path.insert(0, %r)
+        sys.path[:0] = %r
         import numpy as np
         import jax
+        from _fused_ref import solve_unbucketed
         from repro.core import (Problem, SweepEngine, make_sweep_mesh,
-                                random_problem, solve_schedule_dp_batch)
+                                random_problem)
 
         assert len(jax.devices()) == 8, jax.devices()
         rng = np.random.default_rng(5)
@@ -374,25 +377,57 @@ def test_sharded_solve_matches_single_device_bit_identical():
         eng_sh = SweepEngine(mesh=mesh)
         X_sh = eng_sh.solve(probs)
         X_1 = SweepEngine().solve(probs)
-        X_un = solve_schedule_dp_batch(probs)
+        X_un = solve_unbucketed(probs)
         assert np.array_equal(X_sh, X_1), "sharded != single-device"
-        assert np.array_equal(X_sh, X_un), "sharded != uncached"
+        assert np.array_equal(X_sh, X_un), "sharded != unbucketed"
 
         # drifted re-solve stays warm AND sharded-exact
         probs2 = [Problem(T=p.T, lower=p.lower, upper=p.upper,
                           cost_tables=tuple(t * 1.03 for t in p.cost_tables))
                   for p in probs]
         X2 = eng_sh.solve(probs2)
-        assert np.array_equal(X2, solve_schedule_dp_batch(probs2))
+        assert np.array_equal(X2, solve_unbucketed(probs2))
         s = eng_sh.cache_stats()
         assert s["compiles"] == 1 and s["hits"] == 1, s
 
         # B=3 exercises rounding the bucket up to a device-count multiple
         X3 = eng_sh.solve(probs[:3])
-        assert np.array_equal(X3, solve_schedule_dp_batch(probs[:3]))
+        assert np.array_equal(X3, solve_unbucketed(probs[:3]))
+
+        # argmins narrowed to int8 (W 64) and int16 (W 256) on every
+        # device: schedules equal the int32 argmin path's
+        import jax.numpy as jnp
+        from _int32_dp import fused_int32
+        from repro.core.jax_dp import pack_problem
+        from repro.core.problem import ProblemBatch
+
+        for U in (63, 255):
+            wide = []
+            for b in range(3):
+                n = int(rng.integers(3, 12))
+                marg = rng.integers(1, 40, size=(n, U)).astype(float)
+                marg[0] = -1.0  # client 0's curve falls: it takes the whole band
+                tables = [np.concatenate([[500.0], 500.0 + np.cumsum(m)]) for m in marg]
+                T = int(rng.integers(U, n * U * 2 // 3 + 1))
+                wide.append(Problem(T=T, lower=np.zeros(n, np.int64),
+                                    upper=np.full(n, U, np.int64), cost_tables=tables))
+            X_wide = eng_sh.solve(wide)
+            b0 = ProblemBatch.from_problems(wide)
+            X32, _ = fused_int32(pack_problem(b0), jnp.asarray(b0.T, jnp.int32),
+                                 int(b0.T.max()), "blocked")
+            X32 = np.asarray(X32)
+            assert np.array_equal(X_wide, X32), f"sharded at U={U} != int32 argmin path"
+            assert int(X32.max()) == U
+
+        # fleet solve riding on the sharded engine stays exact at q=1
+        from repro.core import Solver
+        p = random_problem(np.random.default_rng(3), n=16, T=40)
+        fsol = Solver(engine=eng_sh).solve_fleet(p, clusters=4, quantum=1)
+        flat = Solver(engine=SweepEngine()).solve([p], algorithm="dp_batch")
+        assert abs(fsol.objective - float(flat.objectives[0])) <= 1e-6
         print("SHARDED_OK")
         """
-        % os.path.join(REPO, "src")
+        % [os.path.join(REPO, "src"), os.path.dirname(os.path.abspath(__file__))]
     )
     proc = subprocess.run(
         [sys.executable, "-c", src], capture_output=True, text=True, timeout=420
